@@ -19,8 +19,11 @@ import argparse
 import sys
 import traceback
 
+from repro.launch.compile_cache import use_compile_cache
+
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true", help="larger shapes/sweeps")
     ap.add_argument("--only", default=None)

@@ -24,9 +24,10 @@ A = jnp.asarray(rng.standard_normal((4096, 64)), jnp.float32)     # (K, N)
 out = vlut_gemm(packed, A)                                        # (M, N) f32
 print("vlut_gemm:", out.shape, out.dtype)
 
-# 3. Same thing through the TPU kernel wrappers (Pallas on TPU, shardable
-#    XLA decode path elsewhere) — bit-identical integer results.
-out_kernel = vlut_mpgemm(packed, A, impl="decode", interpret=True)
+# 3. Same thing through the fused Pallas kernel — compiled on a TPU, run by
+#    the Pallas interpreter elsewhere — bit-identical integer results.
+on_tpu = jax.default_backend() == "tpu"
+out_kernel = vlut_mpgemm(packed, A, impl="decode", interpret=not on_tpu)
 ref = ref_mpgemm(packed, A)
 print("kernel max |err| vs oracle:", float(jnp.max(jnp.abs(out_kernel - ref))))
 

@@ -31,17 +31,21 @@ import time
 from typing import Callable, Iterable
 
 _R = 3
-#: default per-kernel VMEM working-set budget (§4 K_tile rule, TPU-adapted).
-#: This constant is the SINGLE source of truth for every budget consumer —
+#: default per-kernel VMEM budget: the scoped VMEM limit every Vec-LUT
+#: pallas_call requests from Mosaic (``vmem_limit_bytes``), and the ceiling
+#: `tile_vmem_bytes` is checked against when tiles are chosen. A TPU v5e
+#: core has 128 MiB of VMEM; Mosaic's default scoped limit of 16 MiB is too
+#: small for the g=5 lookup table at the smallest legal tile. This constant
+#: is the SINGLE source of truth for every budget consumer — the kernels,
 #: candidate enumeration here, `ops.select_tiles`, and the R5 lint rule all
 #: resolve it through :func:`vmem_budget_bytes` so they can never drift.
-VMEM_BUDGET_BYTES = 4 * 2**20
+VMEM_BUDGET_BYTES = 64 * 2**20
 
 CACHE_ENV = "REPRO_VLUT_AUTOTUNE_CACHE"
 TUNE_ENV = "REPRO_VLUT_AUTOTUNE"
 #: env override for the VMEM budget (bytes) — hardware generations differ
-#: (v4: 16 MiB/core usable, v5e: ~64 MiB shared); the autotuner AND the R5
-#: lint rule both read this, so an override re-tunes and re-lints coherently
+#: in VMEM size; the kernels, the autotuner AND the R5 lint rule all read
+#: this, so an override re-tunes and re-lints coherently
 VMEM_BUDGET_ENV = "REPRO_VLUT_VMEM_BUDGET"
 
 
@@ -60,9 +64,14 @@ def vmem_budget_bytes() -> int:
             return v
     return VMEM_BUDGET_BYTES
 
+# Every ladder entry is a legal Mosaic block by construction: bm a multiple
+# of 8 sublanes, bn a multiple of 128 lanes, and bkg (the lane dim of the
+# (bm, bkg) packed-weight block) a multiple of 128. Clamping to the problem
+# keeps that: bm/bn clamp to the 8/128-padded extent, and bkg clamps to the
+# whole K-group dim, which is legal at any size.
 _BM_CANDIDATES = (64, 128, 256)
 _BN_CANDIDATES = (128, 256, 512)
-_BKG_CANDIDATES = (8, 16, 32, 64, 128, 256)
+_BKG_CANDIDATES = (128, 256, 512)
 
 
 def _round_up(x: int, mult: int) -> int:
@@ -72,10 +81,18 @@ def _round_up(x: int, mult: int) -> int:
 def tile_vmem_bytes(
     g: int, impl: str, bm: int, bn: int, bkg: int, *, fused: bool = True
 ) -> int:
-    """Working-set bytes of one grid step (W + A + table + out + scratch)."""
+    """Working-set bytes of one grid step (W + A + table + out + scratch).
+
+    The lookup kernel's table dominates: it lives as the int32 matmul
+    result, its int32 high part and the two transposed int8 halves (10 B an
+    entry), beside the int8 one-hot (bm, bkg, 3^g) and the int32 per-k
+    partial products (bkg, bm, bn)."""
     w = bm * bkg                                   # uint8 codes
     a = g * bkg * bn * (4 if fused else 1)         # f32 tile (fused) vs int8
-    table = (_R ** g) * bkg * bn * 2 if impl == "lookup" else 0
+    table = (
+        (_R ** g) * bkg * (10 * bn + bm) + bkg * bm * bn * 4
+        if impl == "lookup" else 0
+    )
     out = bm * bn * 4
     acc = bm * bn * 4 if fused else 0
     scales = 4 * (bm + bn) if fused else 0
@@ -89,28 +106,18 @@ def heuristic_tiles(
     *,
     fused: bool = False,
 ) -> dict:
-    """The static §4 rule (the pre-autotune default, and the cold-cache
-    fallback): bn = minimal multiple of the 128-lane width that feeds the
-    MXU (256 for decode — bigger N amortizes the decode), bkg sized so the
-    streamed table fits the budget (lookup) or 128 (decode). With
-    ``fused=True`` the working set additionally holds the f32 activation
-    tile and the int32 scratch accumulator, so bkg shrinks until the whole
-    fused tile fits the same budget. ``vmem_budget=None`` resolves through
-    :func:`vmem_budget_bytes` (env-overridable)."""
+    """The static §4 rule (the cold-cache fallback): bn = minimal multiple
+    of the 128-lane width that feeds the MXU (256 for decode — bigger N
+    amortizes the decode), bkg = 128, the smallest legal K-group tile that
+    is not the whole dimension (ops clamps it to the K-group count when that
+    is smaller). bm halves until the working set (with the fused kernels'
+    f32 activation tile and int32 scratch when ``fused=True``) fits the
+    budget, down to the 8-row sublane floor. ``vmem_budget=None`` resolves
+    through :func:`vmem_budget_bytes` (env-overridable)."""
     budget = vmem_budget if vmem_budget is not None else vmem_budget_bytes()
-    if impl == "lookup":
-        bn = 128
-        bkg = max(8, budget // (_R ** g * bn * 2))
-        bkg = min(128, 1 << (bkg.bit_length() - 1))                 # pow2 clamp
-        t = dict(bm=128, bn=bn, bkg=bkg)
-    else:
-        t = dict(bm=128, bn=256, bkg=128)
-    while (
-        fused
-        and t["bkg"] > 8
-        and tile_vmem_bytes(g, impl, **t, fused=True) > budget
-    ):
-        t["bkg"] //= 2
+    t = dict(bm=128, bn=128 if impl == "lookup" else 256, bkg=128)
+    while t["bm"] > 8 and tile_vmem_bytes(g, impl, **t, fused=fused) > budget:
+        t["bm"] //= 2
     return t
 
 
@@ -299,17 +306,20 @@ def tune(
         g, impl, m, kg, n, fused=fused, interpret=interpret
     )
     trials: list[tuple[dict, float]] = []
+    refused: list[tuple[dict, Exception]] = []
     for t in cands:
         try:
             trials.append((t, float(bench(t))))
-        except Exception:  # noqa: BLE001 — an illegal candidate just loses
-            continue
+        except Exception as e:  # noqa: BLE001 — a refused candidate loses
+            if backend == "interpret":
+                raise  # the interpreter enforces no tiling rules: a bug
+            refused.append((t, e))
     if not trials:
-        # Every candidate failed (transient OOM, busy device, …): return the
-        # heuristic but do NOT poison the persistent cache — a later run
-        # should get another chance to tune this key.
-        best = heuristic_tiles(g, impl, budget, fused=fused)
-        return TuneResult(tiles=best, seconds=float("inf"), trials=trials)
+        t, e = refused[-1]
+        raise RuntimeError(
+            f"every tile candidate for {impl} g={g} (m={m}, kg={kg}, n={n}) "
+            f"was refused on {backend}; last {t}: {e}"
+        ) from e
     best, best_s = min(trials, key=lambda kv: kv[1])
     key = cache_key(g, impl, m, kg, n, backend=backend, fused=fused)
     cache.put(key, best, best_s)

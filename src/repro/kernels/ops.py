@@ -135,17 +135,20 @@ def _resolve_tiles(
     *, fused: bool, interpret: bool, tiles: dict | None,
 ) -> dict:
     """Per-segment tile resolution: explicit override > autotune cache >
-    §4 heuristic (see kernels/autotune.py).
+    §4 heuristic (see kernels/autotune.py). bkg is clamped to the K-group
+    count: a K tile spanning the whole dimension is a legal block at any
+    size and needs no padding.
 
     A fully-specified override skips the autotuner entirely — essential for
     the autotuner's own timing benchmark (segment_mpgemm), which would
     otherwise re-enter tune() for the very key it is measuring."""
     if tiles and all(k in tiles for k in ("bm", "bn", "bkg")):
-        return dict(tiles)
-    t = autotune.get_tiles(g, impl, m, kg, n, fused=fused, interpret=interpret)
-    if tiles:
-        t = dict(t)
-        t.update(tiles)
+        t = dict(tiles)
+    else:
+        t = dict(autotune.get_tiles(
+            g, impl, m, kg, n, fused=fused, interpret=interpret))
+        t.update(tiles or {})
+    t["bkg"] = min(t["bkg"], kg)
     return t
 
 

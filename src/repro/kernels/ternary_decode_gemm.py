@@ -36,6 +36,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .vlut_lookup_gemm import compiler_params
+
 _R = 3
 
 
@@ -135,6 +137,7 @@ def ternary_decode_gemm(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(packed, a_r)
 
@@ -185,5 +188,6 @@ def ternary_decode_gemm_fused(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(packed, a, a_scale, w_scale)

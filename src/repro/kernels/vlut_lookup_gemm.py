@@ -3,7 +3,7 @@
 Implements the Vec-LUT pipeline (paper Alg. 1 + §3.4) per VMEM tile:
 
   1. *LUT precompute in VMEM*: the unified sub-table tile
-     T (3^g, bkg, bn) int16 = S(3^g, g) ⨯ A_r(g, bkg, bn), computed with one
+     T (3^g, bkg, bn) int32 = S(3^g, g) ⨯ A_r(g, bkg, bn), computed with one
      MXU contraction against the compile-time sign-enumeration matrix S
      (the TPU replacement for topological precompute — DESIGN.md §2).
      T lives only in this grid step's VMEM: this is the paper's
@@ -37,9 +37,11 @@ Two lookup strategies (both faithful to "one 1→N lookup per index"):
     closest transliteration of the CPU kernel's inner loop; sublane-serial
     on real hardware (kept for fidelity comparison + ablation).
 
-VMEM budget per §4's K_tile rule (adapted): 3^g · bkg · bn · 2B for T —
-kernels/autotune.py enumerates (bm, bn, bkg) candidates under this budget
-(ops.select_tiles is the cold-cache heuristic).
+VMEM budget per §4's K_tile rule (adapted): the table T dominates the
+working set — kernels/autotune.py models it (`tile_vmem_bytes`) and
+enumerates legal (bm, bn, bkg) candidates under the budget, which is also
+the scoped VMEM limit the kernels request (ops.select_tiles is the
+cold-cache heuristic).
 """
 from __future__ import annotations
 
@@ -50,8 +52,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .autotune import vmem_budget_bytes
 
 _R = 3
+
+
+def compiler_params() -> pltpu.CompilerParams:
+    """Scoped VMEM limit = the autotuner's budget, which candidate tiles
+    are checked against (Mosaic's 16 MiB default cannot hold the g=5 table
+    even at the smallest legal tile)."""
+    return pltpu.CompilerParams(vmem_limit_bytes=vmem_budget_bytes())
 
 
 def _lut_block_int(codes, a_r, *, g: int, lookup: str):
@@ -72,20 +82,28 @@ def _lut_block_int(codes, a_r, *, g: int, lookup: str):
         s, a_r,
         dimension_numbers=(((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32,
-    ).astype(jnp.int16)                                             # (3^g, bkg, bn)
+    )                                                               # (3^g, bkg, bn)
 
     # --- 2. 1→N vector lookup + accumulate --------------------------------
     if lookup == "onehot":
-        # onehot[m, k, e] ⨯ T[e, k, n] → batched over k: (bkg, bm, bn)
+        # onehot[m, k, e] ⨯ T[e, k, n] → batched over k: (bkg, bm, bn).
+        # Mosaic has no mixed int8×int16 matmul, so T (|T| ≤ 127·g < 2^10)
+        # is split exactly into int8 halves T = 128·hi + lo, hi ∈ [-5, 4],
+        # lo ∈ [0, 127]: two native int8×int8→int32 MXU contractions.
         eye = jax.lax.broadcasted_iota(jnp.int32, (bm, bkg, n_entries), 2)
-        onehot = (eye == codes[:, :, None]).astype(jnp.int8)
-        part = jax.lax.dot_general(
-            onehot.transpose(1, 0, 2),                              # (bkg, bm, 3^g)
-            t.transpose(1, 0, 2),                                   # (bkg, 3^g, bn)
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.int32,
-        )                                                           # (bkg, bm, bn)
-        return jnp.sum(part, axis=0)
+        onehot = (eye == codes[:, :, None]).astype(jnp.int8).transpose(1, 0, 2)
+        hi = jnp.floor_divide(t, 128)
+
+        def lookup_sum(half):                                       # → (bm, bn)
+            part = jax.lax.dot_general(
+                onehot,                                             # (bkg, bm, 3^g)
+                half.astype(jnp.int8).transpose(1, 0, 2),           # (bkg, 3^g, bn)
+                dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.int32,
+            )                                                       # (bkg, bm, bn)
+            return jnp.sum(part, axis=0)
+
+        return 128 * lookup_sum(hi) + lookup_sum(t - 128 * hi)
     # 'serial' — literal per-(m,k) row gather
     def body_k(k, acc):
         t_k = jax.lax.dynamic_slice(t, (0, k, 0), (n_entries, 1, bn))[:, 0, :]
@@ -151,7 +169,7 @@ def vlut_lookup_gemm(
     g: int,
     bm: int = 128,
     bn: int = 128,
-    bkg: int = 32,
+    bkg: int = 128,
     lookup: str = "onehot",
     interpret: bool = False,
 ) -> jax.Array:
@@ -177,6 +195,7 @@ def vlut_lookup_gemm(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(packed, a_r)
 
@@ -194,7 +213,7 @@ def vlut_lookup_gemm_fused(
     g: int,
     bm: int = 128,
     bn: int = 128,
-    bkg: int = 32,
+    bkg: int = 128,
     lookup: str = "onehot",
     out_dtype=jnp.float32,
     interpret: bool = False,
@@ -231,5 +250,6 @@ def vlut_lookup_gemm_fused(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(packed, a, a_scale, w_scale)

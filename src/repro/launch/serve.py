@@ -31,12 +31,15 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import encdec_init, init_lm, pack_params
 from repro.obs import ObsConfig
 from repro.serve import ContinuousBatchingScheduler, Engine, Request
 
 
-def main():
+def run(argv: list[str] | None = None):
+    """Serve synthetic requests as the command line `argv` says (None reads
+    sys.argv), print the summary line, and return (engine, stats)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -44,7 +47,10 @@ def main():
     ap.add_argument("--slots", type=int, default=8)
     ap.add_argument("--max-len", type=int, default=256)
     ap.add_argument("--max-new", type=int, default=16)
-    ap.add_argument("--prompt-len", type=int, default=32)
+    ap.add_argument("--prompt-len", type=int, default=32,
+                    help="longest prompt, in tokens")
+    ap.add_argument("--min-prompt-len", type=int, default=4,
+                    help="shortest prompt, in tokens")
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--no-pack", action="store_true",
                     help="serve the QAT (unpacked) weights for comparison")
@@ -83,7 +89,9 @@ def main():
                     help="write the JSON metrics snapshot here on exit")
     ap.add_argument("--trace-out", default="",
                     help="write the Perfetto trace_event JSON here on exit")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    if not 1 <= args.min_prompt_len <= args.prompt_len:
+        ap.error("--min-prompt-len must be in [1, --prompt-len]")
     if (args.spec_adaptive or args.spec_tree) and not args.spec_k:
         ap.error("--spec-adaptive/--spec-tree require --spec-k N (N >= 1)")
     if args.token_budget and not args.prefill_chunk:
@@ -137,7 +145,8 @@ def main():
         Request(
             rid=i,
             prompt=rng.integers(
-                0, cfg.vocab, size=rng.integers(4, args.prompt_len + 1)
+                0, cfg.vocab,
+                size=rng.integers(args.min_prompt_len, args.prompt_len + 1),
             ).astype(np.int32),
             max_new_tokens=args.max_new,
         )
@@ -207,6 +216,12 @@ def main():
     )
     for path in obs.finalize():
         print(f"wrote {path}")
+    return engine, stats
+
+
+def main():
+    use_compile_cache()
+    run()
 
 
 if __name__ == "__main__":

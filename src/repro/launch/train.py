@@ -23,11 +23,13 @@ import argparse
 from repro.configs import get_config
 from repro.data import DataConfig
 from repro.dist.fault_tolerance import run_with_restarts
+from repro.launch.compile_cache import use_compile_cache
 from repro.optim import AdamWConfig
 from repro.train import TrainConfig, Trainer
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true", help="reduced config")

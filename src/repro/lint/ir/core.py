@@ -75,8 +75,13 @@ class IREntry:
 # --------------------------------------------------------------------------
 # jaxpr walking
 # --------------------------------------------------------------------------
+#: call primitives whose body is entered positionally (a nested `jax.jit`
+#: traces as the `jit` primitive)
+CALL_LIKE = ("jit", "closed_call", "core_call")
+
+
 def subjaxprs(eqn) -> list:
-    """The Jaxprs nested in one eqn's params (pjit/scan/while/cond bodies).
+    """The Jaxprs nested in one eqn's params (jit/scan/while/cond bodies).
     pallas_call kernels are deliberately EXCLUDED: their jaxpr has Mosaic
     ref/memory semantics the passes do not model — the AST R5 rules and the
     kernel tests own that boundary."""

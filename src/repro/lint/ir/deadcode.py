@@ -12,7 +12,7 @@ Liveness runs backward per jaxpr level. To keep the pass quiet on the
 swept tree, only *expensive* dead eqns are findings: heavy primitives
 (dot/conv/scan/pallas_call/sort) at any size, or any dead eqn whose
 output exceeds ``MIN_DEAD_BYTES``. Effectful eqns are always live.
-pjit bodies are entered with the *caller's* liveness of the call's
+jit bodies are entered with the *caller's* liveness of the call's
 outputs, so an output computed inside a jit but dropped by every caller
 in the graph is found too. scan/while/cond bodies are analyzed with all
 body outputs assumed live (conservative: no false positives from carry
@@ -23,7 +23,9 @@ from __future__ import annotations
 from typing import Iterable
 
 from ..core import Finding
-from .core import IREntry, aval_bytes, fmt_aval, ir_pass, subjaxprs
+from .core import (
+    CALL_LIKE, IREntry, aval_bytes, fmt_aval, ir_pass, subjaxprs,
+)
 
 _HEAVY = {
     "dot_general", "conv_general_dilated", "scan", "while", "pallas_call",
@@ -56,14 +58,14 @@ def _analyze(jaxpr, live_out, entry, findings, where=""):
             for v in eqn.invars:
                 if _is_var(v):
                     live.add(v)
-            # enter pjit-style bodies with the caller's output liveness so
+            # enter jit-style bodies with the caller's output liveness so
             # dead compute *inside* a jit whose result is dropped outside
             # is still found
             name = eqn.primitive.name
             sub = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
             sub = getattr(sub, "jaxpr", sub)
             if sub is not None and hasattr(sub, "eqns"):
-                if name in ("pjit", "closed_call", "core_call"):
+                if name in CALL_LIKE:
                     _analyze(sub, out_live, entry, findings,
                              where=f"{where}{name}/")
                 else:

@@ -27,7 +27,7 @@ quantized values were promoted to float BEFORE any scale was applied and
 then fed the heavy op. (Integer dots over tainted int8/int32 operands are
 the intended datapath and stay silent.)
 
-Sub-jaxpr handling: pjit/closed_call bodies are entered positionally;
+Sub-jaxpr handling: jit/closed_call bodies are entered positionally;
 scan/while bodies iterate taint to a fixpoint over the carry.
 """
 from __future__ import annotations
@@ -37,7 +37,7 @@ from typing import Iterable
 import jax.numpy as jnp
 
 from ..core import Finding
-from .core import IREntry, ir_pass
+from .core import CALL_LIKE, IREntry, ir_pass
 
 _HEAVY = ("dot_general", "conv_general_dilated")
 
@@ -97,9 +97,8 @@ def _analyze(jaxpr, in_taint, entry, findings, depth=0):
                 taint[ov] = False
             continue
         sub = _sub_jaxpr(eqn)
-        if sub is not None and name in ("pjit", "closed_call", "core_call",
-                                        "custom_jvp_call", "custom_vjp_call",
-                                        "remat", "checkpoint"):
+        if sub is not None and name in CALL_LIKE + (
+                "custom_jvp_call", "custom_vjp_call", "remat", "checkpoint"):
             out_t = _analyze(sub, [get(v) for v in eqn.invars],
                              entry, findings, depth + 1)
             for ov, t in zip(eqn.outvars, out_t):

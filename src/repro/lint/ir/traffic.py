@@ -3,7 +3,7 @@
 From eqn shapes alone, estimate the memory the traced graph moves: every
 *materializing* leaf eqn contributes its operand + result bytes (a read
 and a write per array), scan bodies are multiplied by their trip count,
-pjit bodies are entered (the call eqn itself contributes nothing — its
+jit bodies are entered (the call eqn itself contributes nothing — its
 body does), and `pallas_call` contributes only its HBM operands/results
 (kernel-internal VMEM movement is the AST R5 budget rule's
 jurisdiction). Pure layout/view and cheap elementwise eqns
@@ -28,13 +28,11 @@ from __future__ import annotations
 from typing import Iterable
 
 from ..core import Finding
-from .core import IREntry, aval_bytes, ir_pass
+from .core import CALL_LIKE, IREntry, aval_bytes, ir_pass
 
 #: serving-path default (vlut_packed/mad_int8 measure <= ~4x); the
 #: registry overrides per impl for the table-materializing reference impls
 DEFAULT_FACTOR = 8.0
-
-_CALL_LIKE = ("pjit", "closed_call", "core_call")
 
 #: eqns XLA fuses away (views, broadcasts, cheap elementwise/compare):
 #: counted at zero so the estimate tracks materialized traffic
@@ -62,7 +60,7 @@ def estimate_bytes(jaxpr, trip: float = 1.0) -> float:
         name = eqn.primitive.name
         sub = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
         sub = getattr(sub, "jaxpr", sub)
-        if name in _CALL_LIKE and sub is not None:
+        if name in CALL_LIKE and sub is not None:
             total += estimate_bytes(sub, trip)
             continue
         if name == "scan" and sub is not None:

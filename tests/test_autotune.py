@@ -35,12 +35,17 @@ class TestCandidates:
             assert b <= autotune.VMEM_BUDGET_BYTES, (t, b)
             assert t["bn"] % 128 == 0          # N_tile: multiple of lane width
             assert t["bm"] % 8 == 0            # sublane alignment
+            # bkg is the lane dim of the (bm, bkg) packed-weight block
+            assert t["bkg"] % 128 == 0 or t["bkg"] == 1024
 
     def test_lookup_g5_is_table_constrained(self):
-        """3^5·bkg·bn·2B dominates: no g=5 lookup candidate may pair large
-        bkg with large bn (the §4 K_tile rule with VMEM as the cache)."""
-        for t in autotune.candidate_tiles(5, "lookup", 4096, 1024, 512):
-            assert 3 ** 5 * t["bkg"] * t["bn"] * 2 <= autotune.VMEM_BUDGET_BYTES
+        """The 3^5-entry table dominates: no g=5 lookup candidate may pair
+        large bkg with large bn (the §4 K_tile rule with VMEM as the cache);
+        at 4096 x 1024 groups x 512 only the smallest legal bkg and bn fit."""
+        cands = autotune.candidate_tiles(5, "lookup", 4096, 1024, 512)
+        assert cands
+        for t in cands:
+            assert (t["bn"], t["bkg"]) == (128, 128)
 
     def test_clamped_to_problem(self):
         cands = autotune.candidate_tiles(4, "decode", 16, 4, 8)
@@ -143,6 +148,52 @@ class TestTuneAndReuse:
             fused=True, backend="interpret", cache=cache, tune_if_missing=False,
         )
         assert hit == res.tiles
+
+
+class TestRefusedCandidates:
+    """On a hardware backend the compiler may refuse a tile: it loses. If it
+    refuses every tile, tune raises instead of guessing."""
+
+    CANDS = [dict(bm=8, bn=128, bkg=128), dict(bm=8, bn=128, bkg=256)]
+
+    def test_refused_candidate_loses(self, tmp_path):
+        def bench(tiles):
+            if tiles["bkg"] == 128:
+                raise RuntimeError("Mosaic refused the block")
+            return 1.0
+
+        res = autotune.tune(
+            4, "decode", 8, 256, 8, backend="tpu", candidates=self.CANDS,
+            cache=autotune.TileCache(str(tmp_path / "tiles.json")),
+            benchmark=bench,
+        )
+        assert res.tiles == self.CANDS[1]
+        assert [t for t, _ in res.trials] == [self.CANDS[1]]
+
+    def test_every_candidate_refused_raises(self, tmp_path):
+        cache = autotune.TileCache(str(tmp_path / "tiles.json"))
+
+        def bench(tiles):
+            raise RuntimeError("Mosaic refused the block")
+
+        with pytest.raises(RuntimeError, match="every tile candidate"):
+            autotune.tune(
+                4, "decode", 8, 256, 8, backend="tpu", candidates=self.CANDS,
+                cache=cache, benchmark=bench,
+            )
+        assert cache.get(autotune.cache_key(
+            4, "decode", 8, 256, 8, backend="tpu", fused=True)) is None
+
+    def test_interpreter_failure_is_a_bug(self, tmp_path):
+        def bench(tiles):
+            raise ValueError("kernel bug")
+
+        with pytest.raises(ValueError, match="kernel bug"):
+            autotune.tune(
+                4, "decode", 8, 256, 8, interpret=True, candidates=self.CANDS,
+                cache=autotune.TileCache(str(tmp_path / "tiles.json")),
+                benchmark=bench,
+            )
 
 
 class TestDispatchIntegration:

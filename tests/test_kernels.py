@@ -8,6 +8,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import pack_ternary, pack_weight, ternary_quantize
 from repro.kernels import (
+    autotune,
     ref_mpgemm,
     ref_segment_gemm_int,
     select_tiles,
@@ -94,12 +95,13 @@ class TestOpsWrapper:
         np.testing.assert_allclose(y, want, rtol=1e-4, atol=1e-4)
 
     def test_select_tiles_vmem_budget(self):
-        """§4 K_tile rule adapted: streamed table tile must fit the budget."""
+        """§4 K_tile rule adapted: the tile, streamed table included, must
+        fit the VMEM budget, and be a legal Mosaic block."""
         for g in (4, 5):
             t = select_tiles(g, "lookup")
-            table_bytes = (3**g) * t["bkg"] * t["bn"] * 2
-            assert table_bytes <= 4 * 2**20
-            assert t["bn"] % 128 == 0
+            need = autotune.tile_vmem_bytes(g, "lookup", **t, fused=False)
+            assert need <= autotune.vmem_budget_bytes()
+            assert t["bn"] % 128 == 0 and t["bkg"] % 128 == 0
 
 
 class TestDtypeEdges:
