@@ -323,16 +323,6 @@ def tune(
     best, best_s = min(trials, key=lambda kv: kv[1])
     key = cache_key(g, impl, m, kg, n, backend=backend, fused=fused)
     cache.put(key, best, best_s)
-    # observability: feed the measured winner into the installed metrics
-    # registry (per-(shape, impl) timing series + achieved GB/s / GFLOP/s
-    # gauges) so serve-time tuning shows up in the metrics dump
-    from repro import obs as obs_mod
-
-    o = obs_mod.current()
-    if o is not None:
-        o.record_kernel_sample(
-            g=g, impl=impl, m=m, kg=kg, n=n, fused=fused, seconds=best_s
-        )
     return TuneResult(tiles=best, seconds=best_s, trials=trials)
 
 

@@ -123,6 +123,7 @@ def flash_attention(
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)
     return out[:, :, :sq, :]
 

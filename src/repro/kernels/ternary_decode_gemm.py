@@ -139,6 +139,7 @@ def ternary_decode_gemm(
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         compiler_params=compiler_params(),
         interpret=interpret,
+        name="ternary_decode_gemm",
     )(packed, a_r)
 
 
@@ -190,4 +191,5 @@ def ternary_decode_gemm_fused(
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         compiler_params=compiler_params(),
         interpret=interpret,
+        name="ternary_decode_gemm_fused",
     )(packed, a, a_scale, w_scale)

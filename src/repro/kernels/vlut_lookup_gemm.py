@@ -197,6 +197,7 @@ def vlut_lookup_gemm(
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         compiler_params=compiler_params(),
         interpret=interpret,
+        name="vlut_lookup_gemm",
     )(packed, a_r)
 
 
@@ -252,4 +253,5 @@ def vlut_lookup_gemm_fused(
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         compiler_params=compiler_params(),
         interpret=interpret,
+        name="vlut_lookup_gemm_fused",
     )(packed, a, a_scale, w_scale)

@@ -9,17 +9,22 @@ touches jax arrays or adds device work.
 Wiring (see docs/observability.md):
 
   * ``Engine(obs=ObsConfig(...))`` — the engine records TTFT/TPOT histograms,
-    per-step wall-time histograms and spans, and per-tick effective-M samples
-    (the parallel-token count the Vec-LUT mpGeMM kernels actually saw — the
-    paper's central variable);
-  * ``ContinuousBatchingScheduler`` — per-tick spans + queue-depth /
-    slot-occupancy gauges synced to engine state every tick;
+    per-step wall-time histograms, a span open for each whole step with its
+    phase spans inside (prepare, host syncs, launch, commit, rollback, pager
+    flush), and per-tick effective-M samples (the parallel-token count the
+    Vec-LUT mpGeMM kernels actually saw — the paper's central variable);
+  * ``ContinuousBatchingScheduler`` — per-tick spans (admission, completion
+    bookkeeping) + queue-depth / slot-occupancy gauges synced to engine
+    state every tick;
   * ``kernels/ops.ternary_matmul`` — trace-time mpGeMM dispatch spans
     annotated with (M, N, K, impl, fusion, tile);
-  * ``kernels/autotune.tune`` — per-(shape, impl) timing samples + achieved
-    GB/s / GFLOP/s gauges (bytes/FLOPs from roofline.analysis.mpgemm_cost);
+  * Python's collector — an ``engine.gc`` span around each collection
+    (``gc.callbacks``, registered while an instance is installed);
   * ``launch.serve --metrics-out/--trace-out/--stats-interval`` — exports and
     registry-backed periodic stats lines.
+
+Every span is also a ``jax.profiler.TraceAnnotation`` (see ``trace``), so a
+profiler trace shows the program's phases on the device trace's clock.
 
 Kernel-side hooks discover the active instance through ``install()`` /
 ``current()`` (module global): the kernels cannot take an `obs` parameter
@@ -29,6 +34,7 @@ being observed in practice. ``install(None)`` detaches.
 from __future__ import annotations
 
 import dataclasses
+import gc
 import time
 
 from .metrics import (
@@ -38,7 +44,7 @@ from .metrics import (
     TTFT_BUCKETS,
     MetricsRegistry,
 )
-from .trace import _NULL_SPAN, Tracer
+from .trace import _NULL_SPAN, _Span, Tracer
 
 __all__ = [
     "ObsConfig", "Obs", "NULL_OBS", "install", "current",
@@ -142,23 +148,41 @@ class Obs:
         return time.perf_counter()
 
     def span(self, name: str, **args):
+        """A host span: a ring event on exit and, while a profiler trace
+        runs, a ``jax.profiler.TraceAnnotation`` of the same name. Off, the
+        shared null span (no allocation, no clock read)."""
         if not self.enabled:
             return _NULL_SPAN
-        return self.tracer.span(name, **args)
+        return _Span(self.tracer, name, args)
+
+    def step_span(self, kind: str):
+        """Span open for one whole batched engine step, ring event
+        ``engine_step/{kind}``. The engine sets ``m_real`` and ``m_padded``
+        (and its extras) in ``args`` before it closes; the close feeds the
+        step-time histogram and the effective-M series."""
+        if not self.enabled:
+            return _NULL_SPAN
+        return _Span(self.tracer, f"engine_step/{kind}", {},
+                     done=lambda sp: self._record_step(
+                         kind, sp.t1 - sp.t0, sp.args.get("m_real", 0)))
+
+    def _record_step(self, kind: str, seconds: float, m_real: int) -> None:
+        self.registry.histogram(
+            "repro:engine_step_seconds", "batched step wall time",
+            labels={"kind": kind}, buckets=STEP_BUCKETS,
+        ).observe(seconds)
+        self.s_eff_m.record(m_real)
+        self.h_eff_m.observe(m_real)
 
     def step_event(self, kind: str, t0: float, m_real: int, m_padded: int,
                    **extra) -> None:
         """One batched engine step ran over `m_real` real parallel tokens
-        (`m_padded` including pad rows) in [t0, now]."""
+        (`m_padded` including pad rows) in [t0, now], recorded after the
+        fact (the speculative steps; the others use `step_span`)."""
         if not self.enabled:
             return
         t1 = time.perf_counter()
-        self.registry.histogram(
-            "repro:engine_step_seconds", "batched step wall time",
-            labels={"kind": kind}, buckets=STEP_BUCKETS,
-        ).observe(t1 - t0)
-        self.s_eff_m.record(m_real)
-        self.h_eff_m.observe(m_real)
+        self._record_step(kind, t1 - t0, m_real)
         self.tracer.complete(
             f"engine_step/{kind}", t0, t1,
             args=dict(m_real=int(m_real), m_padded=int(m_padded), **extra),
@@ -201,7 +225,7 @@ class Obs:
             self.c_pages_in.sync_to(pager.pages_paged_in)
             self.c_pages_dropped.sync_to(pager.pages_dropped)
 
-    # -- kernel hooks (ops.py / autotune.py via install()/current()) -----
+    # -- kernel hook (ops.py via install()/current()) ------------------
     def mpgemm_span(self, m_tokens: int, k: int, n_out: int, impl: str,
                     fusion: str, tiles=None):
         """Trace-time span around one mpGeMM dispatch. m_tokens is the
@@ -217,30 +241,6 @@ class Obs:
             "mpgemm_dispatch", m=int(m_tokens), k=int(k), n=int(n_out),
             impl=str(impl), fusion=str(fusion), tile=tiles,
         )
-
-    def record_kernel_sample(self, *, g: int, impl: str, m: int, kg: int,
-                             n: int, fused: bool, seconds: float) -> None:
-        """One measured kernel timing (autotune trial winner / benchmark):
-        per-(shape, impl) series + achieved-bandwidth/compute gauges. Here
-        (m, kg·g) is the weight shape and n the parallel-token count (the
-        autotuner's convention)."""
-        if not self.enabled or seconds <= 0:
-            return
-        labels = {"impl": str(impl), "g": str(g), "shape": f"{m}x{kg * g}",
-                  "m_tokens": str(n)}
-        self.registry.series(
-            "repro:mpgemm_kernel_seconds", "measured kernel wall seconds",
-            labels=labels, capacity=self.config.series_capacity,
-        ).record(seconds)
-        from repro.roofline.analysis import mpgemm_cost
-
-        flops, bytes_ = mpgemm_cost(m, kg * g, n, g, fused=fused)
-        self.registry.gauge(
-            "repro:mpgemm_achieved_gflops", "achieved GFLOP/s (last sample)",
-            labels=labels).set(flops / seconds / 1e9)
-        self.registry.gauge(
-            "repro:mpgemm_achieved_gbps", "achieved HBM GB/s (last sample)",
-            labels=labels).set(bytes_ / seconds / 1e9)
 
     # -- reporting -------------------------------------------------------
     def stats_line(self) -> str:
@@ -291,12 +291,33 @@ class Obs:
 NULL_OBS = Obs(ObsConfig(enabled=False))
 
 _current: Obs | None = None
+_gc_open = None      # the engine.gc span of the collection under way
+
+
+def _gc_span(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: an ``engine.gc`` span around each collection
+    while an instance is installed (a full collection walks every tracked
+    object, the trace ring's events included)."""
+    global _gc_open
+    if phase == "start" and _current is not None:
+        _gc_open = _current.span("engine.gc", generation=info["generation"])
+        _gc_open.__enter__()
+    elif phase == "stop" and _gc_open is not None:
+        _gc_open.args["collected"] = info["collected"]
+        sp, _gc_open = _gc_open, None
+        sp.__exit__(None, None, None)
 
 
 def install(obs: Obs | None) -> None:
-    """Publish `obs` to the kernel-side hooks (ops/autotune); None detaches."""
+    """Publish `obs` to the kernel-side hook (ops) and the collector hook;
+    None detaches both."""
     global _current
     _current = obs if (obs is not None and obs.enabled) else None
+    if _current is None:
+        if _gc_span in gc.callbacks:
+            gc.callbacks.remove(_gc_span)
+    elif _gc_span not in gc.callbacks:
+        gc.callbacks.append(_gc_span)
 
 
 def current() -> Obs | None:

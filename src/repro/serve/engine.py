@@ -252,6 +252,11 @@ class Engine:
         self.slot_req: dict[int, Request] = {}
         self.last_token = jnp.zeros((max_slots, 1), jnp.int32)
         self.active = np.zeros(max_slots, bool)
+        # obs on only: host clock when the last step's sampled tokens reached
+        # the host; the next step's launch_gap_us counts from it. The
+        # scheduler clears it when a tick leaves no work (the device then
+        # waits for requests, not for the host)
+        self.last_sync_t: float | None = None
 
         self._prefill1 = jax.jit(
             lambda p, c, t: model_prefill(p, t, c, cfg, mode=mode)
@@ -455,7 +460,8 @@ class Engine:
             # contrast to the permanent exceeds-model-context ValueError.
             need = len(req.prompt) + req.max_new_tokens - 1 + self._draft_window
             try:
-                matched = self.pager.admit(slot, np.asarray(req.prompt), need)
+                with self.obs.span("engine.pager.admit"):
+                    matched = self.pager.admit(slot, np.asarray(req.prompt), need)
             except OutOfPages as e:
                 req.error = f"queued: waiting for free KV pages ({e})"
                 return False
@@ -539,7 +545,8 @@ class Engine:
             req.t_done = req.t_first_token
             self.slot_free[slot] = True
             if self.pager is not None:
-                self.pager.release(slot, np.asarray(req.prompt))
+                with self.obs.span("engine.pager.release"):
+                    self.pager.release(slot, np.asarray(req.prompt))
             return
         self.slot_free[slot] = False
         self.slot_req[slot] = req
@@ -578,15 +585,16 @@ class Engine:
         (before the prefill pass) and at tick start (after releases)."""
         if self.pager is None or not self.pager.dirty:
             return
-        tab, fresh = self.pager.take_flush()
-        if fresh:
-            w = self.pager.cfg.scrub_batch
-            fresh = fresh + [self.pager.n_pages] * ((-len(fresh)) % w)
-            for i in range(0, len(fresh), w):
-                self.cache = self._scrub(
-                    self.cache, jnp.asarray(fresh[i:i + w], jnp.int32)
-                )
-        self.cache = self._set_tab(self.cache, jnp.asarray(tab, jnp.int32))
+        with self.obs.span("engine.pager.flush"):
+            tab, fresh = self.pager.take_flush()
+            if fresh:
+                w = self.pager.cfg.scrub_batch
+                fresh = fresh + [self.pager.n_pages] * ((-len(fresh)) % w)
+                for i in range(0, len(fresh), w):
+                    self.cache = self._scrub(
+                        self.cache, jnp.asarray(fresh[i:i + w], jnp.int32)
+                    )
+            self.cache = self._set_tab(self.cache, jnp.asarray(tab, jnp.int32))
 
     def _slot_exhausted(self, req: Request) -> bool:
         """True when the slot has no room for another decode (or verify)
@@ -616,7 +624,8 @@ class Engine:
             # this prompt prefix admits at near-zero prefill cost), the rest
             # to the free pool; the block-table flush is deferred to the
             # next admission or tick (no jitted step runs before either)
-            self.pager.release(slot, np.asarray(req.prompt))
+            with self.obs.span("engine.pager.release"):
+                self.pager.release(slot, np.asarray(req.prompt))
         if self.drafter is not None:
             self.drafter.on_release(slot)
 
@@ -680,70 +689,115 @@ class Engine:
         are mandatory and count first, then prefill chunks are granted FCFS
         (admission order); at least one chunk always advances so prefill
         can never starve."""
-        _t0 = time.perf_counter() if self.obs.enabled else 0.0
-        chunk = self.prefill_chunk
-        include_decode = self._decode_rides and bool(self.active.any())
-        used = int(self.active.sum()) if include_decode else 0
-        budget = self.token_budget
-        chosen: list[tuple[int, int]] = []
-        for slot, req in self.prefilling.items():
-            c = min(chunk, len(req.prompt) - req.prefill_pos)
-            if chosen and budget and used + c > budget:
-                break
-            chosen.append((slot, c))
-            used += c
-        tokens = np.zeros((self.max_slots, chunk), np.int32)
-        col = np.zeros(self.max_slots, np.int64)     # logits column per slot
-        new_idx = self._idx_vector()
+        obs = self.obs
+        with obs.step_span("chunk") as step:
+            chunk = self.prefill_chunk
+            include_decode = self._decode_rides and bool(self.active.any())
+            if include_decode:
+                with obs.span("engine.sync.last_token") as sync_last:
+                    last = np.asarray(self.last_token)[:, 0]
+            with obs.span("engine.chunk.prepare") as prep:
+                used = int(self.active.sum()) if include_decode else 0
+                budget = self.token_budget
+                chosen: list[tuple[int, int]] = []
+                for slot, req in self.prefilling.items():
+                    c = min(chunk, len(req.prompt) - req.prefill_pos)
+                    if chosen and budget and used + c > budget:
+                        break
+                    chosen.append((slot, c))
+                    used += c
+                tokens = np.zeros((self.max_slots, chunk), np.int32)
+                col = np.zeros(self.max_slots, np.int64)  # logits column per slot
+                new_idx = self._idx_vector()
+                for slot, c in chosen:
+                    req = self.prefilling[slot]
+                    tokens[slot, :c] = req.prompt[req.prefill_pos:req.prefill_pos + c]
+                    col[slot] = c - 1
+                    new_idx[slot] = req.prefill_pos + c
+                decode_slots: list[int] = []
+                if include_decode:
+                    for slot, req in self.slot_req.items():
+                        if not self.active[slot]:
+                            continue
+                        tokens[slot, 0] = last[slot]
+                        new_idx[slot] += 1      # idx_vector holds last_token's pos
+                        decode_slots.append(slot)
+                if obs.enabled:
+                    work = self._chunk_work(chosen, decode_slots, new_idx)
+            with obs.span("engine.chunk.launch") as launch:
+                with kernel_ops.dispatch_override(**self._mpgemm):
+                    rows, cache = self._chunk_verify(
+                        self.params, self.cache, jnp.asarray(tokens),
+                        jnp.asarray(col, np.int32),
+                    )                                            # rows: (B, V)
+            with obs.span("engine.sync.tokens") as sync:
+                nxt = np.asarray(self._sample(rows))
+            with obs.span("engine.chunk.commit") as commit:
+                now = time.perf_counter()
+                self.chunk_steps += 1
+                for slot, c in chosen:
+                    req = self.prefilling[slot]
+                    req.prefill_pos += c
+                    self.prefill_tokens += c
+                    self.prefill_pad_tokens += chunk - c
+                    if req.prefill_pos < len(req.prompt):
+                        continue
+                    # final chunk landed: first token, PREFILLING → DECODING
+                    del self.prefilling[slot]
+                    self._start_decoding(slot, req, int(nxt[slot]), now)
+                for slot in decode_slots:
+                    req = self.slot_req[slot]
+                    self.decode_tokens += 1
+                    req.generated.append(int(nxt[slot]))
+                    self.last_token = self.last_token.at[slot, 0].set(
+                        nxt[slot], mode="drop"
+                    )
+                    if (len(req.generated) >= req.max_new_tokens
+                            or self._slot_exhausted(req)):
+                        self._finish_slot(slot, req, now)
+            with obs.span("engine.rollback") as rollback:
+                self.cache = rollback_cache(cache, jnp.asarray(new_idx))
+            if obs.enabled:
+                # used = real tokens this step carried (chunk tokens + decode
+                # rows) — the effective M the batched mpGeMM dispatch saw
+                step.args.update(
+                    m_real=used, m_padded=self.max_slots * chunk,
+                    prefills=len(chosen), decodes=len(decode_slots), **work,
+                    **self._phase_args(
+                        prep, launch, sync, commit, rollback,
+                        sync_last.us if include_decode else 0.0,
+                    ),
+                )
+
+    def _chunk_work(self, chosen: list[tuple[int, int]],
+                    decode_slots: list[int], new_idx: np.ndarray) -> dict:
+        """What a chunk step computes, before its commit (obs on only):
+        free slots, keys attended summed over the real tokens (a token at
+        position p attends p + 1 keys) and rows whose logits serve a token
+        (decode rows and final chunks)."""
+        keys = sum(int(new_idx[s]) for s in decode_slots)   # p + 1 each
+        served = len(decode_slots)
         for slot, c in chosen:
             req = self.prefilling[slot]
-            tokens[slot, :c] = req.prompt[req.prefill_pos:req.prefill_pos + c]
-            col[slot] = c - 1
-            new_idx[slot] = req.prefill_pos + c
-        decode_slots: list[int] = []
-        if include_decode:
-            last = np.asarray(self.last_token)[:, 0]
-            for slot, req in self.slot_req.items():
-                if not self.active[slot]:
-                    continue
-                tokens[slot, 0] = last[slot]
-                new_idx[slot] += 1          # idx_vector holds last_token's pos
-                decode_slots.append(slot)
-        with kernel_ops.dispatch_override(**self._mpgemm):
-            rows, cache = self._chunk_verify(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(col, np.int32),
-            )                                                    # rows: (B, V)
-        nxt = np.asarray(self._sample(rows))
-        now = time.perf_counter()
-        self.chunk_steps += 1
-        for slot, c in chosen:
-            req = self.prefilling[slot]
-            req.prefill_pos += c
-            self.prefill_tokens += c
-            self.prefill_pad_tokens += chunk - c
-            if req.prefill_pos < len(req.prompt):
-                continue
-            # final chunk landed: first token, PREFILLING → DECODING
-            del self.prefilling[slot]
-            self._start_decoding(slot, req, int(nxt[slot]), now)
-        for slot in decode_slots:
-            req = self.slot_req[slot]
-            self.decode_tokens += 1
-            req.generated.append(int(nxt[slot]))
-            self.last_token = self.last_token.at[slot, 0].set(
-                nxt[slot], mode="drop"
-            )
-            if len(req.generated) >= req.max_new_tokens or self._slot_exhausted(req):
-                self._finish_slot(slot, req, now)
-        self.cache = rollback_cache(cache, jnp.asarray(new_idx))
-        if self.obs.enabled:
-            # used = real tokens this step carried (chunk tokens + decode
-            # rows) — the effective M the batched mpGeMM dispatch saw
-            self.obs.step_event(
-                "chunk", _t0, m_real=used, m_padded=self.max_slots * chunk,
-                prefills=len(chosen), decodes=len(decode_slots),
-            )
+            p0 = req.prefill_pos
+            keys += c * p0 + c * (c + 1) // 2
+            served += p0 + c == len(req.prompt)
+        return dict(free_slots=sum(self.slot_free), attn_keys=keys,
+                    logit_rows=served)
+
+    def _phase_args(self, prep, launch, sync, commit, rollback,
+                    sync_extra_us: float = 0.0) -> dict:
+        """A step's phase times in microseconds (obs on only), and the host
+        time since the previous step's tokens reached the host
+        (``launch_gap_us``, when the previous tick ran a step)."""
+        args = dict(
+            prepare_us=prep.us, sync_us=sync.us + sync_extra_us,
+            commit_us=commit.us, rollback_us=rollback.us if rollback else 0.0,
+        )
+        if self.last_sync_t is not None:
+            args["launch_gap_us"] = (launch.t0 - self.last_sync_t) * 1e6
+        self.last_sync_t = sync.t1
+        return args
 
     def decode_once(self):
         """One batched decode step over every active slot. With spec enabled
@@ -755,35 +809,57 @@ class Engine:
             return self._decode_spec_tree()
         if self.spec is not None:
             return self._decode_spec()
-        self.decode_steps += 1
-        _t0 = time.perf_counter() if self.obs.enabled else 0.0
-        _m_active = int(self.active.sum())   # rows finishing mid-loop still counted
-        # the jit'd decode step advances EVERY slot's idx by 1 and scatters
-        # a (garbage) token at every slot's frontier; with slots mid-chunked-
-        # prefill that drift must be undone — the restored frontier index is
-        # rewritten by the slot's next chunk before it can be attended
-        restore = bool(self.prefilling)
-        if restore:
-            new_idx = self._idx_vector()
-            new_idx[np.asarray(self.active)] += 1    # decode wrote last_token
-        with kernel_ops.dispatch_override(**self._mpgemm):
-            logits, self.cache = self._decode(self.params, self.cache, self.last_token)
-        nxt = np.asarray(self._sample(logits))                       # (B,)
-        self.last_token = jnp.asarray(nxt)[:, None]
-        now = time.perf_counter()
-        for slot, req in list(self.slot_req.items()):
-            if not self.active[slot]:
-                continue
-            self.decode_tokens += 1
-            req.generated.append(int(nxt[slot]))
-            if len(req.generated) >= req.max_new_tokens or self._slot_exhausted(req):
-                self._finish_slot(slot, req, now)
-        if restore:
-            self.cache = rollback_cache(self.cache, jnp.asarray(new_idx))
-        if self.obs.enabled:
-            self.obs.step_event(
-                "decode", _t0, m_real=_m_active, m_padded=self.max_slots,
-            )
+        obs = self.obs
+        rollback = None
+        with obs.step_span("decode") as step:
+            with obs.span("engine.decode.prepare") as prep:
+                self.decode_steps += 1
+                # the jit'd decode step advances EVERY slot's idx by 1 and
+                # scatters a (garbage) token at every slot's frontier; with
+                # slots mid-chunked-prefill that drift must be undone — the
+                # restored frontier index is rewritten by the slot's next
+                # chunk before it can be attended
+                restore = bool(self.prefilling)
+                if restore:
+                    new_idx = self._idx_vector()
+                    new_idx[np.asarray(self.active)] += 1  # decode wrote last_token
+                if obs.enabled:
+                    # every active row counts, finishing in this step or not;
+                    # the last token sits at len(prompt) + len(generated) - 1
+                    rows = int(self.active.sum())
+                    work = dict(
+                        m_real=rows, free_slots=sum(self.slot_free),
+                        attn_keys=sum(len(r.prompt) + len(r.generated)
+                                      for s, r in self.slot_req.items()
+                                      if self.active[s]),
+                        logit_rows=rows,
+                    )
+            with obs.span("engine.decode.launch") as launch:
+                with kernel_ops.dispatch_override(**self._mpgemm):
+                    logits, self.cache = self._decode(
+                        self.params, self.cache, self.last_token
+                    )
+            with obs.span("engine.sync.tokens") as sync:
+                nxt = np.asarray(self._sample(logits))               # (B,)
+            with obs.span("engine.decode.commit") as commit:
+                self.last_token = jnp.asarray(nxt)[:, None]
+                now = time.perf_counter()
+                for slot, req in list(self.slot_req.items()):
+                    if not self.active[slot]:
+                        continue
+                    self.decode_tokens += 1
+                    req.generated.append(int(nxt[slot]))
+                    if (len(req.generated) >= req.max_new_tokens
+                            or self._slot_exhausted(req)):
+                        self._finish_slot(slot, req, now)
+            if restore:
+                with obs.span("engine.rollback") as rollback:
+                    self.cache = rollback_cache(self.cache, jnp.asarray(new_idx))
+            if obs.enabled:
+                step.args.update(
+                    m_padded=self.max_slots, **work,
+                    **self._phase_args(prep, launch, sync, commit, rollback),
+                )
 
     def _choose_k_eff(self) -> np.ndarray:
         """Per-slot effective draft length for this step: spec.k everywhere

@@ -144,44 +144,47 @@ class ContinuousBatchingScheduler:
         skips the batched step instead of burning a dispatch on an empty
         batch."""
         obs = self.engine.obs
-        _t0 = time.perf_counter() if obs.enabled else 0.0
-        multi = bool(self.engine.prefill_chunk)
-        while self.queue:
-            head = self.queue[0]
-            try:
-                if not self.engine.add(head):
-                    break              # no free slot — head stays queued
-                self.queue.popleft()
-                if head.done:          # satisfied by prefill alone
-                    self.completed.append(head)
-                if not multi:
-                    break              # one blocking admission per tick
-            except ValueError as e:
-                head.error = str(e)
-                self.rejected.append(head)
-                self.queue.popleft()   # rejected in place; try the next
-        before = list(self.engine.slot_req.values()) + list(
-            self.engine.prefilling.values()
-        )
-        if self.engine.has_work:
-            self.engine.step()
-        for r in before:
-            if r.done:                 # finished this step (decode or final
-                self.completed.append(r)  # chunk with max_new_tokens=1)
-        if obs.enabled:
-            # end-of-tick state sync: queue depth + slot occupancy gauges,
-            # counter mirrors — the registry reads engine state, never
-            # double-counts it
-            obs.on_tick(
-                self.engine, queue_depth=len(self.queue),
-                completed=len(self.completed), rejected=len(self.rejected),
-            )
-            obs.tracer.complete(
-                "scheduler_tick", _t0,
-                args=dict(queue=len(self.queue),
-                          running=int(self.engine.active.sum()),
-                          prefilling=len(self.engine.prefilling)),
-            )
+        with obs.span("scheduler_tick") as tick:
+            with obs.span("scheduler.admit"):
+                multi = bool(self.engine.prefill_chunk)
+                while self.queue:
+                    head = self.queue[0]
+                    try:
+                        if not self.engine.add(head):
+                            break          # no free slot — head stays queued
+                        self.queue.popleft()
+                        if head.done:      # satisfied by prefill alone
+                            self.completed.append(head)
+                        if not multi:
+                            break          # one blocking admission per tick
+                    except ValueError as e:
+                        head.error = str(e)
+                        self.rejected.append(head)
+                        self.queue.popleft()  # rejected in place; try the next
+                before = list(self.engine.slot_req.values()) + list(
+                    self.engine.prefilling.values()
+                )
+            if self.engine.has_work:
+                self.engine.step()
+            with obs.span("scheduler.finish"):
+                for r in before:
+                    if r.done:         # finished this step (decode or final
+                        self.completed.append(r)  # chunk, max_new_tokens=1)
+                if obs.enabled:
+                    # end-of-tick state sync: queue depth + slot occupancy
+                    # gauges, counter mirrors — the registry reads engine
+                    # state, never double-counts it
+                    obs.on_tick(
+                        self.engine, queue_depth=len(self.queue),
+                        completed=len(self.completed),
+                        rejected=len(self.rejected),
+                    )
+                    tick.args.update(queue=len(self.queue),
+                                     running=int(self.engine.active.sum()),
+                                     prefilling=len(self.engine.prefilling))
+                    if not self.engine.has_work:
+                        # the next step waits on requests, not on the host
+                        self.engine.last_sync_t = None
 
     def run_to_completion(self, max_ticks: int = 100_000) -> ServeStats:
         """Drain the queue (≤ max_ticks); → ServeStats for this run.

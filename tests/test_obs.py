@@ -1,6 +1,5 @@
 """Observability layer: metrics registry, tracer, null path, engine wiring."""
 import json
-import math
 
 import jax
 import numpy as np
@@ -175,8 +174,7 @@ class TestNullPath:
         NULL_OBS.step_event("decode", 0.0, m_real=1, m_padded=1)
         NULL_OBS.observe_ttft(1.0)
         NULL_OBS.on_tick(None, queue_depth=0, completed=0, rejected=0)
-        NULL_OBS.record_kernel_sample(
-            g=4, impl="lut", m=8, kg=2, n=1, fused=True, seconds=1e-3)
+        assert NULL_OBS.step_span("chunk") is _NULL_SPAN
         assert not NULL_OBS.registry.all()
         assert not NULL_OBS.tracer.events
         assert NULL_OBS.stats_line() == "obs disabled"
@@ -223,16 +221,42 @@ class TestObsFacade:
         assert (ev["args"]["m"], ev["args"]["k"], ev["args"]["n"]) == (
             16, 2048, 512)
 
-    def test_record_kernel_sample_gauges(self):
+    def test_step_span(self):
+        """The step span is open for the whole step: its ring event keeps
+        the engine_step/{kind} name and the args set inside it, and its
+        close feeds the step histogram and the effective-M series."""
         o = Obs(ObsConfig())
-        o.record_kernel_sample(
-            g=4, impl="lut", m=512, kg=512, n=16, fused=True, seconds=1e-3)
-        labels = {"impl": "lut", "g": "4", "shape": "512x2048",
-                  "m_tokens": "16"}
-        gf = o.registry.find("repro:mpgemm_achieved_gflops", labels)
-        gb = o.registry.find("repro:mpgemm_achieved_gbps", labels)
-        assert gf.value > 0 and gb.value > 0
-        assert math.isfinite(gf.value)
+        with o.step_span("chunk") as sp:
+            with o.span("engine.chunk.prepare") as inner:
+                pass
+            sp.args.update(m_real=24, m_padded=32, prefills=3)
+        h = o.registry.find("repro:engine_step_seconds", {"kind": "chunk"})
+        assert h.count == 1 and h.sum == pytest.approx(sp.t1 - sp.t0)
+        assert list(o.s_eff_m.samples) == [24.0]
+        step_ev, inner_ev = o.tracer.events[-1], o.tracer.events[-2]
+        assert step_ev["name"] == "engine_step/chunk"
+        assert step_ev["args"] == {"m_real": 24, "m_padded": 32, "prefills": 3}
+        assert inner_ev["name"] == "engine.chunk.prepare"
+        assert sp.t0 <= inner.t0 <= inner.t1 <= sp.t1
+        assert inner.us == pytest.approx(inner_ev["dur"])
+
+    def test_gc_span_while_installed(self):
+        """An installed instance gets an engine.gc span around each Python
+        collection; detaching unregisters the collector hook."""
+        import gc
+
+        o = Obs(ObsConfig())
+        obs_mod.install(o)
+        assert obs_mod._gc_span in gc.callbacks
+        gc.collect()
+        ev = [e for e in o.tracer.events if e["name"] == "engine.gc"]
+        assert ev and ev[-1]["args"]["generation"] == 2
+        assert "collected" in ev[-1]["args"]
+        obs_mod.install(None)
+        assert obs_mod._gc_span not in gc.callbacks
+        n = len(o.tracer.events)
+        gc.collect()
+        assert len(o.tracer.events) == n
 
     def test_finalize_writes_exports(self, tmp_path):
         o = Obs(ObsConfig(
@@ -344,3 +368,157 @@ class TestEngineIntegration:
         assert not eng.obs.registry.all()
         assert not eng.obs.tracer.events
         assert obs_mod.current() is None
+
+
+# --------------------------------------------------------------------------
+# Phase spans inside the engine and scheduler
+# --------------------------------------------------------------------------
+#: the spans one tick of a chunked, paged, unspeculated engine can open
+PHASES = (
+    "scheduler.admit", "scheduler.finish", "engine.pager.admit",
+    "engine.pager.flush", "engine.pager.release",
+    "engine.sync.last_token", "engine.chunk.prepare", "engine.chunk.launch",
+    "engine.sync.tokens", "engine.chunk.commit", "engine.rollback",
+    "engine.decode.prepare", "engine.decode.launch", "engine.decode.commit",
+)
+STEPS = ("engine_step/chunk", "engine_step/decode")
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    """A two-layer model at toy widths: what the spans record does not
+    depend on the model's size."""
+    from repro.configs.base import ModelConfig, uniform_layers
+    from repro.models import init_lm, pack_params
+
+    cfg = ModelConfig(name="tiny", n_layers=2, d_model=64, n_heads=4,
+                      n_kv_heads=2, head_dim=16, d_ff=128, vocab=256,
+                      layers=uniform_layers(2))
+    return cfg, pack_params(init_lm(jax.random.PRNGKey(0), cfg), cfg)
+
+
+def _engine(tiny, obs):
+    from repro.serve import ContinuousBatchingScheduler, Engine
+    from repro.serve.paging import PagedKVConfig
+
+    cfg, params = tiny
+    eng = Engine(params, cfg, max_slots=3, max_len=32, prefill_chunk=4,
+                 paged_kv=PagedKVConfig(page_size=4), obs=obs)
+    return eng, ContinuousBatchingScheduler(eng)
+
+
+def _submit(sched, specs, rng):
+    from repro.serve import Request
+
+    reqs = [Request(rid=i, prompt=rng.integers(0, 256, n).astype(np.int32),
+                    max_new_tokens=new) for i, (n, new) in enumerate(specs)]
+    sched.submit(reqs)
+    return reqs
+
+
+def _drain(sched):
+    while sched.queue or sched.engine.has_work:
+        sched.tick()
+
+
+class TestProgramSpans:
+    def test_phase_spans_land_in_profiler_trace(self, tiny, rng, tmp_path):
+        """Served under jax.profiler: every phase span is on the host plane
+        of the .xplane.pb, inside its step (engine phases) or its tick
+        (scheduler phases), as many times as the ring recorded it."""
+        from jax.profiler import ProfileData
+
+        eng, sched = _engine(tiny, ObsConfig())
+        _submit(sched, [(6, 3), (3, 2), (9, 4)], rng)
+        _drain(sched)                        # compiles outside the trace
+        base = len(eng.obs.tracer.events)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            _submit(sched, [(6, 3), (3, 2), (9, 4), (5, 5)], rng)
+            _drain(sched)
+        finally:
+            jax.profiler.stop_trace()
+        ring = list(eng.obs.tracer.events)[base:]
+        host = []
+        (path,) = tmp_path.glob("**/*.xplane.pb")
+        for plane in ProfileData.from_file(str(path)).planes:
+            if plane.name.startswith("/host:CPU"):
+                host += [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                         for line in plane.lines for e in line.events]
+        count = lambda evs, name: sum(e[0] == name for e in evs)
+        names = [e["name"] for e in ring]
+        for name in PHASES + STEPS + ("scheduler_tick",):
+            assert names.count(name) > 0, name
+            assert count(host, name) == names.count(name), name
+
+        def inside(ev, outer_names):
+            return any(o[0] in outer_names and o[1] <= ev[1] and ev[2] <= o[2]
+                       for o in host)
+
+        for ev in host:
+            if ev[0].startswith(("engine.sync", "engine.chunk",
+                                 "engine.decode", "engine.rollback")):
+                assert inside(ev, STEPS), ev
+            elif ev[0] in STEPS + ("scheduler.admit", "scheduler.finish",
+                                   "engine.pager.admit", "engine.pager.flush",
+                                   "engine.pager.release"):
+                assert inside(ev, ("scheduler_tick",)), ev
+
+    def test_obs_off_opens_no_span(self, tiny, rng, monkeypatch):
+        """With obs off nothing enters a profiler annotation (it would
+        raise here) and the tracer records nothing."""
+        import gc
+
+        class Refused:
+            def __init__(self, *a, **k):
+                raise AssertionError("annotation opened with obs off")
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Refused)
+        eng, sched = _engine(tiny, None)
+        assert eng.obs is NULL_OBS
+        reqs = _submit(sched, [(6, 3), (3, 2), (9, 4)], rng)
+        _drain(sched)
+        assert all(r.done for r in reqs)
+        assert eng.last_sync_t is None
+        assert not NULL_OBS.tracer.events and not NULL_OBS.registry.all()
+        assert obs_mod._gc_span not in gc.callbacks
+
+    def test_step_args_on_a_scripted_sequence(self, tiny, rng):
+        """Slots 3, chunk 4. A: 6-token prompt, 3 new; B: 3-token prompt,
+        2 new. Tick 1 chunks A[0:4] and all of B (B's first token); tick 2
+        chunks A[4:6] with B's decode row riding (B done, A's first
+        token); ticks 3 and 4 decode A alone."""
+        eng, sched = _engine(tiny, ObsConfig())
+        _submit(sched, [(6, 3), (3, 2)], rng)
+        _drain(sched)
+        steps = [e for e in eng.obs.tracer.events
+                 if e["name"].startswith("engine_step/")]
+        keys = ("m_real", "prefills", "decodes", "free_slots", "attn_keys",
+                "logit_rows")
+        got = [(e["name"],) + tuple(e["args"].get(k) for k in keys)
+               for e in steps]
+        # attn_keys: a token at position p attends p + 1 keys
+        assert got == [
+            ("engine_step/chunk", 7, 2, 0, 1, (1 + 2 + 3 + 4) + (1 + 2 + 3), 1),
+            ("engine_step/chunk", 3, 1, 1, 1, (5 + 6) + 4, 2),
+            ("engine_step/decode", 1, None, None, 2, 7, 1),
+            ("engine_step/decode", 1, None, None, 2, 8, 1),
+        ]
+        assert "launch_gap_us" not in steps[0]["args"]
+        for e in steps:
+            a = e["args"]
+            phases = [a["prepare_us"], a["sync_us"], a["commit_us"],
+                      a["rollback_us"]]
+            assert min(phases) >= 0.0
+            assert sum(phases) <= e["dur"]
+        for e in steps[1:]:
+            assert e["args"]["launch_gap_us"] > 0.0
+        assert steps[0]["args"]["rollback_us"] > 0.0      # chunk tail
+        assert steps[2]["args"]["rollback_us"] == 0.0     # nothing prefills
+        # the drained engine has no work, so the mark is cleared
+        assert eng.last_sync_t is None
+        # the phases' own ring events match the args
+        prep = [e["dur"] for e in eng.obs.tracer.events
+                if e["name"] in ("engine.chunk.prepare",
+                                 "engine.decode.prepare")]
+        assert prep == pytest.approx([e["args"]["prepare_us"] for e in steps])

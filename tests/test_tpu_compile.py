@@ -100,3 +100,47 @@ def test_engine_step_compiles(one_chip, model_shapes, step, width):
     tokens = jax.ShapeDtypeStruct((SLOTS, width), jnp.int32, sharding=one_chip)
     with ops.dispatch_override(impl="decode"):
         assert _custom_calls(jitted, params, cache, tokens) > 0
+
+
+def _kernel_call(name, one_chip):
+    """(jitted caller, argument shapes) of one Pallas kernel at a small
+    shape, called inside an outer program as the model calls it."""
+    from repro.kernels.flash_attention import flash_attention
+    from repro.kernels.ternary_decode_gemm import (
+        ternary_decode_gemm, ternary_decode_gemm_fused,
+    )
+    from repro.kernels.vlut_lookup_gemm import (
+        vlut_lookup_gemm, vlut_lookup_gemm_fused,
+    )
+
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    m, kg, g, n = 1024, 128, 5, 512
+    if name == "flash_attention":
+        q, kv = s((1, 4, 256, 128), jnp.bfloat16), s((1, 2, 256, 128), jnp.bfloat16)
+        return jax.jit(lambda q, k, v: flash_attention(q, k, v) * 2), (q, kv, kv)
+    fn = {"ternary_decode_gemm": ternary_decode_gemm,
+          "ternary_decode_gemm_fused": ternary_decode_gemm_fused,
+          "vlut_lookup_gemm": vlut_lookup_gemm,
+          "vlut_lookup_gemm_fused": vlut_lookup_gemm_fused}[name]
+    tiles = dict(g=g, bm=128, bn=128, bkg=128)
+    if name.endswith("_fused"):
+        args = (s((m, kg), jnp.uint8), s((kg, g, n), jnp.bfloat16),
+                s((1, n), jnp.float32), s((m, 1), jnp.float32))
+        return jax.jit(lambda *a: fn(*a, **tiles) * 2.0), args
+    args = (s((m, kg), jnp.uint8), s((g, kg, n), jnp.int8))
+    return jax.jit(lambda *a: fn(*a, **tiles) + 1), args
+
+
+@pytest.mark.parametrize("name", [
+    "ternary_decode_gemm", "ternary_decode_gemm_fused", "vlut_lookup_gemm",
+    "vlut_lookup_gemm_fused", "flash_attention"])
+def test_kernel_keeps_its_name(one_chip, name):
+    """A kernel's device-trace events carry its HLO instruction name; the
+    benchmark's trace reduction finds the mpGeMM kernels by these names,
+    so each pallas_call names itself and the compiled program keeps it."""
+    import re
+
+    jitted, args = _kernel_call(name, one_chip)
+    text = jitted.lower(*args).compile().as_text()
+    calls = re.findall(r"%([A-Za-z_\-]+)[.0-9]* = \S+ custom-call\(", text)
+    assert calls == [name]
