@@ -1,8 +1,9 @@
 """repro.kernels — Pallas TPU kernels for the Vec-LUT mpGeMM hot spot.
 
 The hot path is the **fused single-pass pipeline** (paper §3.3): float
-activations stream into the kernel, each grid step quantizes its tile against
-the per-token scale in VMEM and de-interleaves in registers, and the
+activations stream into the kernel, which quantizes each tile against the
+per-token scale in VMEM and de-interleaves it there (the decode kernel once
+per call, keeping the int8 token tile for every weight-row tile), and the
 w_scale × a_scale dequant epilogue runs on the last K step — no int8
 activation buffer, de-interleave rematerialization, or int32 output ever
 round-trips through HBM. Tile sizes come from the measured autotuner with

@@ -79,14 +79,18 @@ def _round_up(x: int, mult: int) -> int:
 
 
 def tile_vmem_bytes(
-    g: int, impl: str, bm: int, bn: int, bkg: int, *, fused: bool = True
+    g: int, impl: str, bm: int, bn: int, bkg: int, *, fused: bool = True,
+    kg: int | None = None,
 ) -> int:
     """Working-set bytes of one grid step (W + A + table + out + scratch).
 
     The lookup kernel's table dominates: it lives as the int32 matmul
     result, its int32 high part and the two transposed int8 halves (10 B an
     entry), beside the int8 one-hot (bm, bkg, 3^g) and the int32 per-k
-    partial products (bkg, bm, bn)."""
+    partial products (bkg, bm, bn). The fused decode kernel keeps its token
+    tile quantized over the whole K extent, an int8 (g, KG_pad, bn) scratch
+    with KG_pad the K-group count padded to the clamped bkg; without `kg`
+    it is counted as one K tile."""
     w = bm * bkg                                   # uint8 codes
     a = g * bkg * bn * (4 if fused else 1)         # f32 tile (fused) vs int8
     table = (
@@ -96,7 +100,10 @@ def tile_vmem_bytes(
     out = bm * bn * 4
     acc = bm * bn * 4 if fused else 0
     scales = 4 * (bm + bn) if fused else 0
-    return w + a + table + out + acc + scales
+    a_q = 0
+    if impl == "decode" and fused:
+        a_q = g * (_round_up(kg, min(bkg, kg)) if kg else bkg) * bn
+    return w + a + table + out + acc + scales + a_q
 
 
 def heuristic_tiles(
@@ -105,18 +112,21 @@ def heuristic_tiles(
     vmem_budget: int | None = None,
     *,
     fused: bool = False,
+    kg: int | None = None,
 ) -> dict:
     """The static §4 rule (the cold-cache fallback): bn = minimal multiple
     of the 128-lane width that feeds the MXU (256 for decode — bigger N
     amortizes the decode), bkg = 128, the smallest legal K-group tile that
     is not the whole dimension (ops clamps it to the K-group count when that
     is smaller). bm halves until the working set (with the fused kernels'
-    f32 activation tile and int32 scratch when ``fused=True``) fits the
-    budget, down to the 8-row sublane floor. ``vmem_budget=None`` resolves
-    through :func:`vmem_budget_bytes` (env-overridable)."""
+    f32 activation tile and scratches when ``fused=True``, over `kg` K-groups
+    when given) fits the budget, down to the 8-row sublane floor.
+    ``vmem_budget=None`` resolves through :func:`vmem_budget_bytes`
+    (env-overridable)."""
     budget = vmem_budget if vmem_budget is not None else vmem_budget_bytes()
     t = dict(bm=128, bn=128 if impl == "lookup" else 256, bkg=128)
-    while t["bm"] > 8 and tile_vmem_bytes(g, impl, **t, fused=fused) > budget:
+    while (t["bm"] > 8
+           and tile_vmem_bytes(g, impl, **t, fused=fused, kg=kg) > budget):
         t["bm"] //= 2
     return t
 
@@ -150,12 +160,14 @@ def candidate_tiles(
                 key = (bm, bn, bkg)
                 if key in seen:
                     continue
-                if tile_vmem_bytes(g, impl, bm, bn, bkg, fused=fused) > budget:
+                if tile_vmem_bytes(
+                    g, impl, bm, bn, bkg, fused=fused, kg=kg
+                ) > budget:
                     continue
                 seen.add(key)
                 out.append(dict(bm=bm, bn=bn, bkg=bkg))
     if not out:
-        out.append(heuristic_tiles(g, impl, budget, fused=fused))
+        out.append(heuristic_tiles(g, impl, budget, fused=fused, kg=kg))
     return out
 
 
@@ -364,4 +376,4 @@ def get_tiles(
             fused=fused, backend=backend, interpret=interpret,
             cache=cache, benchmark=benchmark,
         ).tiles
-    return heuristic_tiles(g, impl, fused=fused)
+    return heuristic_tiles(g, impl, fused=fused, kg=kg)

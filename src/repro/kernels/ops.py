@@ -2,12 +2,14 @@
 
 The hot path is **single-pass** (paper §3.3 "fused activation and output
 transformation"): float activations go straight into the Pallas kernel, which
-quantizes each (bkg, bn) tile against the per-token scale in VMEM (prologue),
-de-interleaves in registers from the free (K//g, g, N) row-major view, and
-applies the w_scale × a_scale dequant epilogue on the last K grid step —
-emitting f32/bf16 directly. The only HBM tensors are the packed weights, the
-float activation, and the float output: no int8 activation buffer, no
-de-interleave rematerialization, no int32 output round-trip.
+quantizes each (bkg, bn) tile against the per-token scale in VMEM (prologue;
+the decode kernel does so once per call and keeps the int8 token tile for
+every weight-row tile), de-interleaves in VMEM from the free (K//g, g, N)
+row-major view, and applies the w_scale × a_scale dequant epilogue on the
+last K grid step — emitting f32/bf16 directly. The only HBM tensors are the
+packed weights, the float activation, and the float output: no int8
+activation buffer, no de-interleave rematerialization, no int32 output
+round-trip.
 
 Responsibilities:
   * per-token activation scale (one cheap reduction; shared with the QAT
@@ -40,7 +42,9 @@ from repro import obs as obs_mod
 from repro.core.packing import PackedWeight
 from repro.core.quantize import act_quant_tokens, act_token_scale
 from . import autotune
-from .ternary_decode_gemm import ternary_decode_gemm, ternary_decode_gemm_fused
+from .ternary_decode_gemm import (
+    grid_counts, ternary_decode_gemm, ternary_decode_gemm_fused,
+)
 from .vlut_lookup_gemm import vlut_lookup_gemm, vlut_lookup_gemm_fused
 
 _R = 3
@@ -393,6 +397,33 @@ def _peek_tiles(pw: PackedWeight, n_tokens: int, impl: str, fusion: str,
     return hit if hit is not None else "heuristic"
 
 
+def _act_tiles(pw: PackedWeight, n_tokens: int, impl: str, fusion: str,
+               interpret: bool) -> dict:
+    """Activation tiles the kernel calls fetch from HBM (`act_tile_loads`,
+    summed over segments) and the weight-row tiles that share each fetch
+    (`act_reuse`), with the tiles dispatch resolves (cache or heuristic;
+    never tunes). The fused decode kernel holds its activation tile across
+    the row tiles (nn·nk loads, reuse nm); the other kernels fetch it again
+    for every row tile (nm·nn·nk loads, reuse 1) unless there is only one
+    activation tile. Empty for impl="xla"."""
+    if impl == "xla":
+        return {}
+    loads, reuse = 0, []
+    for packed, _, _, g in _segments(pw):
+        m, kg = packed.shape
+        t = autotune.get_tiles(
+            g, impl, m, kg, n_tokens, fused=fusion == "fused",
+            interpret=interpret, tune_if_missing=False,
+        )
+        nm, nn, nk = grid_counts(m, kg, n_tokens, t["bm"], t["bn"], t["bkg"])
+        stationary = (impl == "decode" and fusion == "fused") or nn * nk == 1
+        loads += nn * nk * (1 if stationary else nm)
+        reuse.append(nm if stationary else 1)
+    if not reuse:
+        return {}
+    return {"act_tile_loads": loads, "act_reuse": min(reuse)}
+
+
 def ternary_matmul(
     pw: PackedWeight,
     x: jax.Array,
@@ -428,6 +459,7 @@ def ternary_matmul(
             m_tokens=a.shape[1], k=a.shape[0], n_out=pw.M, impl=impl,
             fusion=fusion,
             tiles=_peek_tiles(pw, a.shape[1], impl, fusion, cfg.interpret),
+            **_act_tiles(pw, a.shape[1], impl, fusion, cfg.interpret),
         )
     else:
         span = contextlib.nullcontext()

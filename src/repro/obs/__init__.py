@@ -227,9 +227,13 @@ class Obs:
 
     # -- kernel hook (ops.py via install()/current()) ------------------
     def mpgemm_span(self, m_tokens: int, k: int, n_out: int, impl: str,
-                    fusion: str, tiles=None):
+                    fusion: str, tiles=None, act_tile_loads=None,
+                    act_reuse=None):
         """Trace-time span around one mpGeMM dispatch. m_tokens is the
-        paper's M (parallel tokens); n_out × k is the weight shape."""
+        paper's M (parallel tokens); n_out × k is the weight shape. On the
+        Pallas paths `act_tile_loads` (activation tiles fetched from HBM
+        per call) and `act_reuse` (weight-row tiles served by each fetch)
+        describe the kernel's schedule."""
         if not self.enabled:
             return _NULL_SPAN
         self.registry.counter(
@@ -240,6 +244,7 @@ class Obs:
         return self.tracer.span(
             "mpgemm_dispatch", m=int(m_tokens), k=int(k), n=int(n_out),
             impl=str(impl), fusion=str(fusion), tile=tiles,
+            act_tile_loads=act_tile_loads, act_reuse=act_reuse,
         )
 
     # -- reporting -------------------------------------------------------

@@ -58,6 +58,67 @@ class TestCandidates:
                 assert select_tiles(g, impl) == autotune.heuristic_tiles(g, impl)
 
 
+def _largest_k() -> int:
+    """The widest input of any ternary linear among the repo's configs: the
+    model width, the MLP and expert widths, the attention output width, the
+    MLA latents and the SSM inner width."""
+    from repro.configs import get_config, list_archs
+
+    widths = []
+    for arch in list_archs():
+        c = get_config(arch)
+        widths += [c.d_model, c.d_ff, c.n_heads * c.head_dim]
+        widths += [spec.d_ff for spec in c.layers]
+        if c.moe:
+            widths += [c.moe.d_ff_expert, c.moe.d_ff_shared]
+        if c.mla:
+            widths += [c.mla.q_lora_rank, c.mla.kv_lora_rank,
+                       c.n_heads * c.mla.v_dim]
+        if c.ssm:
+            widths.append(c.ssm.d_inner)
+    return max(widths)
+
+
+class TestFusedDecodeScratch:
+    """The fused decode kernel keeps its token tile quantized over the whole
+    K extent in an int8 (g, KG_pad, bn) VMEM scratch; the tile model counts
+    it and the heuristic stays within the budget with it."""
+
+    @pytest.mark.parametrize("g,kg,kg_pad,bkg", [
+        (5, 408, 512, 128),      # internlm2-1.8b q, k, v, up, gate (K 2048)
+        (5, 1636, 1664, 128),    # internlm2-1.8b down (K 8192)
+        (4, 2, 2, 128),          # a g=4 remainder segment: bkg clamps to 2
+    ])
+    def test_tile_model_counts_int8_scratch(self, g, kg, kg_pad, bkg):
+        bm, bn = 128, 256
+        with_kg = autotune.tile_vmem_bytes(
+            g, "decode", bm, bn, bkg, fused=True, kg=kg)
+        one_tile = autotune.tile_vmem_bytes(g, "decode", bm, bn, bkg, fused=True)
+        assert with_kg - one_tile == g * (kg_pad - bkg) * bn
+        # the other kernels keep no such scratch
+        for impl, fused in (("lookup", True), ("decode", False)):
+            assert autotune.tile_vmem_bytes(
+                g, impl, bm, bn, bkg, fused=fused, kg=kg
+            ) == autotune.tile_vmem_bytes(g, impl, bm, bn, bkg, fused=fused)
+
+    @pytest.mark.parametrize("kg", [512, 1664, "largest"])
+    @pytest.mark.parametrize("g", [4, 5])
+    def test_heuristic_within_budget(self, g, kg, tmp_path):
+        if kg == "largest":
+            kg = _largest_k() // g     # every K-group at this g (i1/i2 packing)
+        t = autotune.heuristic_tiles(g, "decode", fused=True, kg=kg)
+        assert t == dict(bm=128, bn=256, bkg=128)
+        assert autotune.tile_vmem_bytes(
+            g, "decode", **t, fused=True, kg=kg
+        ) <= autotune.VMEM_BUDGET_BYTES
+        # dispatch resolves the same tiles on a cold cache
+        assert autotune.get_tiles(
+            g, "decode", 2048, kg, 4096, fused=True, backend="test",
+            cache=autotune.TileCache(str(tmp_path / "tiles.json")),
+            tune_if_missing=False,
+        ) == t
+
+
 class TestCacheRoundTrip:
     def test_disk_round_trip(self, tmp_path):
         path = str(tmp_path / "tiles.json")

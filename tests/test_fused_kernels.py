@@ -30,22 +30,35 @@ def _mk(m, k, n, seed=0):
     return tw, jnp.asarray(a)
 
 
+_ref_jit = jax.jit(ref_mpgemm)
+
 # odd M/K/N on purpose: every axis exercises the padding edge
 ODD_SHAPES = [(8, 13, 3), (5, 20, 1), (33, 45, 17), (64, 97, 130), (127, 24, 7)]
+
+
+# Direct-kernel cases: (impl, g, m, kg, n, activation dtype). The first
+# four keep a 2 x 1 x 2 (row x token x K) grid at bm=8, bn=32, bkg=4; the
+# "grid" cases run a 3 x 3 x 3 grid, so the decode kernel's activation tile
+# is held across three row tiles for each of three token tiles.
+DIRECT_CASES = [
+    pytest.param(impl, g, 16, 8, 32, jnp.float32, id=f"{impl}-{g}")
+    for impl in ("decode", "lookup") for g in (4, 5)
+] + [
+    pytest.param("decode", g, 24, 12, 96, dt, id=f"decode-{g}-grid-{name}")
+    for g in (4, 5) for dt, name in ((jnp.float32, "f32"), (jnp.bfloat16, "bf16"))
+]
 
 
 class TestFusedKernelsDirect:
     """Direct fused-kernel calls against the dense int oracle + exact scales."""
 
-    @pytest.mark.parametrize("g", [4, 5])
-    @pytest.mark.parametrize("impl", ["decode", "lookup"])
-    def test_single_segment_exact(self, g, impl, rng):
-        m, kg, n = 16, 8, 32
+    @pytest.mark.parametrize("impl,g,m,kg,n,dtype", DIRECT_CASES)
+    def test_single_segment_exact(self, impl, g, m, kg, n, dtype, rng):
         k = kg * g
         w = rng.integers(-1, 2, (m, k)).astype(np.int8)
         a = rng.standard_normal((k, n)).astype(np.float32)
         packed = pack_ternary(jnp.asarray(w), g)
-        a_j = jnp.asarray(a)
+        a_j = jnp.asarray(a).astype(dtype)
         a_q, a_scale = act_quant_tokens(a_j)
         want_int = np.asarray(ref_segment_gemm_int(packed, a_q, g))
         want = want_int.astype(np.float32) * np.asarray(a_scale)[None, :]
@@ -91,6 +104,25 @@ class TestFusedKernelsDirect:
         assert np.all(np.asarray(out)[:, n:] == 0)
 
 
+# Pipeline parity cases: (impl, packing mode, m, kg, n, activation dtype).
+# The first four are one-tile problems (24 x 40 x 9). The "grid" cases run
+# the heuristic tiles (bm 128, bn 256, bkg 128) on 2 x 2 x 2 grids with
+# padding on every axis: m = 203 rows (pad to 208), kg = 150 K-groups (pad to
+# 256), n = 300 tokens (pad to 384). The "decode" cases are a decode step's
+# shape: 16 tokens, padded to one 128-wide token tile.
+UNFUSED_PARITY_CASES = [
+    pytest.param(impl, mode, 24, 8 if mode == "i1" else 10, 9, jnp.float32,
+                 id=f"{mode}-{impl}")
+    for mode in ("i1", "i2") for impl in ("decode", "lookup")
+] + [
+    pytest.param("decode", mode, 203, 150, n, dt,
+                 id=f"{mode}-decode-{shape}-{name}")
+    for mode in ("i1", "i2")
+    for n, shape in ((300, "grid"), (16, "decode"))
+    for dt, name in ((jnp.float32, "f32"), (jnp.bfloat16, "bf16"))
+]
+
+
 class TestFusedPipeline:
     """vlut_mpgemm(fusion='fused') — the single-pass hot path."""
 
@@ -105,14 +137,19 @@ class TestFusedPipeline:
         want = np.asarray(ref_mpgemm(pw, a))
         np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-5)
 
-    @pytest.mark.parametrize("impl", ["decode", "lookup"])
-    @pytest.mark.parametrize("mode", ["i1", "i2"])
-    def test_single_segment_bit_identical_to_unfused(self, impl, mode):
+    @pytest.mark.parametrize("impl,mode,m,kg,n,dtype", UNFUSED_PARITY_CASES)
+    def test_single_segment_bit_identical_to_unfused(
+        self, impl, mode, m, kg, n, dtype
+    ):
         """Same quantizer + same int path + same scale-mult order → the fused
         kernel's f32 output is bit-identical to the unfused pipeline when
-        only one segment exists."""
-        k = 40  # 5|40 and 4|40
-        tw, a = _mk(24, k, 9, seed=3)
+        only one segment exists. The reference runs jitted, as the pipelines
+        do (eager and jitted XLA round the token scale differently), and
+        applies the same scales in its own program: it agrees to within f32
+        rounding."""
+        k = kg * (5 if mode == "i1" else 4)
+        tw, a = _mk(m, k, n, seed=3)
+        a = a.astype(dtype)
         pw = pack_weight(tw.values, tw.scale, mode)
         fused = np.asarray(
             vlut_mpgemm(pw, a, impl=impl, interpret=True, fusion="fused")
@@ -121,6 +158,9 @@ class TestFusedPipeline:
             vlut_mpgemm(pw, a, impl=impl, interpret=True, fusion="unfused")
         )
         np.testing.assert_array_equal(fused, unfused)
+        np.testing.assert_allclose(
+            fused, np.asarray(_ref_jit(pw, a)), rtol=1e-6, atol=1e-6
+        )
 
     @pytest.mark.parametrize("impl", ["decode", "lookup"])
     def test_mixed_segments_match_unfused(self, impl):

@@ -221,6 +221,39 @@ class TestObsFacade:
         assert (ev["args"]["m"], ev["args"]["k"], ev["args"]["n"]) == (
             16, 2048, 512)
 
+    @pytest.mark.parametrize("fusion,loads,reuse", [
+        ("fused", 2 * 3, 4),          # nn·nk: held across the nm row tiles
+        ("unfused", 4 * 2 * 3, 1),    # nm·nn·nk: fetched for every row tile
+    ])
+    def test_mpgemm_dispatch_act_tiles(self, fusion, loads, reuse, tmp_path):
+        """A traced decode-kernel call reports its activation schedule: at
+        the heuristic tiles (bm 128, bn 256, bkg 128) a 400 x 1500 weight
+        (300 K-groups at g=5) over 300 tokens runs nm 4, nn 2, nk 3."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        from repro.core import pack_weight, ternary_quantize
+        from repro.kernels import autotune, ternary_matmul
+        from repro.kernels import ops as kernel_ops
+
+        w = np.random.default_rng(0).standard_normal((400, 1500))
+        tw = ternary_quantize(jnp.asarray(w, jnp.float32))
+        pw = pack_weight(tw.values, tw.scale, "i1")
+        x = jax.ShapeDtypeStruct((300, 1500), jnp.bfloat16)
+        autotune.reset_default_cache(str(tmp_path / "tiles.json"))
+        o = Obs(ObsConfig())
+        obs_mod.install(o)
+        try:
+            with kernel_ops.dispatch_override(
+                    impl="decode", fusion=fusion, interpret=True):
+                jax.eval_shape(lambda x: ternary_matmul(pw, x), x)
+        finally:
+            obs_mod.install(None)
+            autotune.reset_default_cache()
+        (ev,) = [e for e in o.tracer.events if e["name"] == "mpgemm_dispatch"]
+        assert ev["args"]["act_tile_loads"] == loads
+        assert ev["args"]["act_reuse"] == reuse
+
     def test_step_span(self):
         """The step span is open for the whole step: its ring event keeps
         the engine_step/{kind} name and the args set inside it, and its

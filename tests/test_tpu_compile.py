@@ -144,3 +144,33 @@ def test_kernel_keeps_its_name(one_chip, name):
     text = jitted.lower(*args).compile().as_text()
     calls = re.findall(r"%([A-Za-z_\-]+)[.0-9]* = \S+ custom-call\(", text)
     assert calls == [name]
+
+
+@pytest.mark.parametrize("m,kg", [(8192, 512), (2048, 1664)])
+def test_fused_decode_compiles_at_chunk_step_shapes(one_chip, m, kg):
+    """The fused decode kernel as internlm2-1.8b's chunk step calls it: 4096
+    token rows in bf16 through the MLP up/gate projection (8192 rows, K 2048
+    as 408 K-groups padded to 512) and the down projection (2048 rows, K
+    8192 as 1636 padded to 1664), g=5, heuristic tiles. Its working set,
+    with the int8 token-tile scratch over the whole K extent, fits the
+    budget, Mosaic accepts it at that limit, and it keeps its name."""
+    import re
+
+    from repro.kernels import autotune
+    from repro.kernels.ternary_decode_gemm import ternary_decode_gemm_fused
+
+    g, n = 5, 4096
+    t = autotune.heuristic_tiles(g, "decode", fused=True, kg=kg)
+    assert t == dict(bm=128, bn=256, bkg=128)
+    assert autotune.tile_vmem_bytes(
+        g, "decode", **t, fused=True, kg=kg
+    ) <= autotune.vmem_budget_bytes()
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    jitted = jax.jit(
+        lambda *a: ternary_decode_gemm_fused(*a, g=g, **t) * 2.0)
+    text = jitted.lower(
+        s((m, kg), jnp.uint8), s((kg, g, n), jnp.bfloat16),
+        s((1, n), jnp.float32), s((m, 1), jnp.float32),
+    ).compile().as_text()
+    calls = re.findall(r"%([A-Za-z_\-]+)[.0-9]* = \S+ custom-call\(", text)
+    assert calls == ["ternary_decode_gemm_fused"]
