@@ -38,18 +38,16 @@ def readings(cell, seed: int, seconds: float, *, interpret: bool = False,
 
     lowp = jnp.float8_e4m3fn if lowp is None else lowp
     counter = harness.CompileCounter()
-    engine, sched = harness.build(cell, seed, trace=False, interpret=interpret)
-    harness.warm_up(cell, engine, sched)
+    engine, sched = harness.set_up(cell, seed, trace=False, interpret=interpret)
     run = harness.Run(cell, seed, seconds, False)
     harness.serve_window(run, engine, sched, counter)
     del engine, sched
     gc.collect()
     picked = harness._sample_for_check(run)
     items = [(t.req.prompt, list(t.req.generated)) for t in picked]
-    length = int(cell.engine["max_len"])
-    program = harness.served_gaps(cell.config, seed, items, length)
-    control = harness.served_gaps(cell.config, seed, items, length,
-                                  lowp=lowp, control=True)
+    program = harness.served_gaps(cell.config, seed, items)
+    control = harness.served_gaps(cell.config, seed, items, lowp=lowp,
+                                  control=True)
     return {
         "seed": seed, "requests": len(items),
         "tokens": int(sum(len(g) for g in program)),

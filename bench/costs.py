@@ -1,15 +1,18 @@
 """Operations and bytes that the served model's work needs, from shapes, and
 the table of chip peaks they are measured against.
 
-Every roofline share and MFU the benchmark reports is computed here, so a
-change to the program cannot change the yardstick.
+Every roofline share and MFU the benchmark reports is computed here or in
+the configuration's kind module (``bench/kinds/<kind>.py``, found by
+``kind``), so a change to the program cannot change the yardstick.
 """
 from __future__ import annotations
 
+import importlib.util
 import json
 from pathlib import Path
 
-PEAKS_FILE = Path(__file__).resolve().parent / "peaks.json"
+BENCH = Path(__file__).resolve().parent
+PEAKS_FILE = BENCH / "peaks.json"
 
 
 def peaks_for(device_kind: str) -> dict:
@@ -44,28 +47,16 @@ def least_time(ops: float, nbytes: float, peak_ops: float,
     return max(ops / peak_ops, nbytes / peak_bytes_per_s)
 
 
-def linear_shapes(cfg: dict) -> list[tuple[int, int]]:
-    """(out, in) of every ternary linear in one decoder layer."""
-    d, h, kv, hd, f = (cfg["hidden_size"], cfg["num_attention_heads"],
-                       cfg["num_key_value_heads"], cfg["head_dim"],
-                       cfg["intermediate_size"])
-    return [(h * hd, d), (kv * hd, d), (kv * hd, d), (d, h * hd),
-            (f, d), (f, d), (d, f)]
-
-
-def mpgemm_ops_per_token(cfg: dict) -> float:
-    """Ternary-matmul operations one token needs through the whole stack."""
-    per_layer = sum(2.0 * m * k for m, k in linear_shapes(cfg))
-    return per_layer * cfg["num_hidden_layers"]
-
-
-def attention_ops(cfg: dict, keys: int) -> float:
-    """Operations of one query token attending ``keys`` cached positions in
-    every layer (scores and the weighted sum of values)."""
-    return (4.0 * cfg["num_attention_heads"] * cfg["head_dim"] * keys
-            * cfg["num_hidden_layers"])
-
-
-def head_ops(cfg: dict) -> float:
-    """Operations of one row of output logits."""
-    return 2.0 * cfg["hidden_size"] * cfg["vocab_size"]
+def kind(cfg: dict, bench: Path = BENCH):
+    """The module of a configuration's ``kind``: ``bench/kinds/<kind>.py``,
+    which builds the program's ModelConfig and counts the model's
+    operations. A kind without its file is an error, with no fallback."""
+    path = bench / "kinds" / f"{cfg['kind']}.py"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"configuration {cfg.get('name', '?')!r} is of kind "
+            f"{cfg['kind']!r}, and {path} does not exist")
+    spec = importlib.util.spec_from_file_location(f"kind_{cfg['kind']}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod

@@ -5,11 +5,14 @@ readings.
 A cell is found by its name in ``BENCHMARK.json``; everything that belongs
 to it comes from files named after its parts:
 
-* ``bench/configs/<config>.json``  the model's sizes, and the reference
-  module under ``bench/references/`` that computes it plainly;
+* ``bench/configs/<config>.json``  the model's sizes, its ``kind`` (the
+  module under ``bench/kinds/`` that builds the program's ModelConfig and
+  counts its operations) and the reference module under
+  ``bench/references/`` that computes it plainly;
 * ``bench/traffic/<traffic>.json``  the mix's parameters (``traffic.py``);
-* ``bench/cells/<workload>.json``   the engine's shape for this cell, the
-  mix's per-cell overrides and the limits of the correctness check;
+* ``bench/cells/<workload>.json``   the engine's shape and page pool for
+  this cell, the mix's per-cell overrides and the limits of the
+  correctness check;
 * ``bench/metrics/<metric>.py``     one reader per per-layer metric.
 """
 from __future__ import annotations
@@ -23,9 +26,12 @@ import os
 import shutil
 import sys
 import time
+import types
 from pathlib import Path
 
 import numpy as np
+
+import costs
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -40,8 +46,10 @@ TILES = BENCH / "tiles.json"
 class Cell:
     name: str
     config: dict          # bench/configs/<config>.json
+    kind: types.ModuleType  # bench/kinds/<config's kind>.py, loaded
     mix: dict             # traffic file merged with the cell's overrides
-    engine: dict          # max_slots, max_len, prefill_chunk, page_size
+    engine: dict          # max_slots, max_len, prefill_chunk, page_size,
+                          # optionally n_pages
     check: dict           # correctness sample size and limits
     chips: int = 1
     end_to_end: list = dataclasses.field(default_factory=list)
@@ -76,7 +84,8 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
     reported = {m["name"] for m in e2e}
     per_layer = [m for m in bench["per_layer"] if _applies(m, workload, reported)]
     return Cell(
-        name=workload, config=config, mix=mix, engine=cell["engine"],
+        name=workload, config=config, kind=costs.kind(config, cdir), mix=mix,
+        engine=cell["engine"],
         check=cell["check"], chips=int(wl["chips"]), end_to_end=e2e,
         per_layer=per_layer, trace_seconds=float(cell.get("trace_seconds", 2.0)),
     )
@@ -100,37 +109,32 @@ def use_compile_cache() -> None:
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 
-def model_config(cfg: dict):
-    """The program's ModelConfig for a configuration file."""
-    from repro.configs.base import ModelConfig, uniform_layers
-
-    n = cfg["num_hidden_layers"]
-    return ModelConfig(
-        name=cfg["name"], n_layers=n, d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
-        layers=uniform_layers(n, rope_theta=float(cfg["rope_theta"])),
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        norm_eps=float(cfg["rms_norm_eps"]), dtype=cfg["dtype"],
-    )
-
-
 class CompileCounter:
-    """Counts JAX compilations (and traces) as they happen."""
+    """Counts JAX compilations (and traces) as they happen, the seconds spent
+    compiling, and the persistent compile cache's hits and misses."""
 
-    EVENTS = ("/jax/core/compile/backend_compile_duration",
-              "/jax/core/compile/jaxpr_trace_duration")
+    COMPILE = "/jax/core/compile/backend_compile_duration"
+    EVENTS = (COMPILE, "/jax/core/compile/jaxpr_trace_duration")
 
     def __init__(self):
         import jax
 
         self.count = 0
+        self.compile_s = 0.0
+        self.cache = {"hits": 0, "misses": 0}
         jax.monitoring.register_event_duration_secs_listener(self._on)
+        jax.monitoring.register_event_listener(self._on_event)
 
     def _on(self, event: str, duration: float, **_):
         if event in self.EVENTS:
             self.count += 1
+        if event == self.COMPILE:
+            self.compile_s += duration
+
+    def _on_event(self, event: str, **_):
+        kind = event.rpartition("/jax/compilation_cache/cache_")[2]
+        if kind in self.cache:
+            self.cache[kind] += 1
 
 
 @dataclasses.dataclass
@@ -139,17 +143,6 @@ class Tracked:
     req: object
     due: float                 # absolute perf_counter time it was due
     in_window: bool
-
-
-@dataclasses.dataclass
-class StepRecord:
-    """One engine step, as the benchmark saw it from outside."""
-    t0: float
-    t1: float
-    kind: str                  # "chunk" or "decode"
-    real_tokens: int
-    attn_keys: float           # sum over real tokens of the keys attended
-    logit_rows: int            # rows whose logits produce a served token
 
 
 def percentile(values: list[float], q: float) -> float:
@@ -165,38 +158,24 @@ class Run:
     def __init__(self, cell: Cell, seed: int, seconds: float, trace: bool):
         self.cell, self.seed, self.seconds, self.trace = cell, seed, seconds, trace
         self.tracked: list[Tracked] = []
-        self.steps: list[StepRecord] = []
         self.spans: list[dict] = []
         self.trace_data: dict | None = None
         self.peaks: dict = {}
         self.t_base = self.t_open = self.t_close = 0.0   # traffic starts,
                                    # the window opens, the window closes
         self.tokens_in_window = 0
+        #: the engine's token counters as the window opens and closes
+        self.counts_open: dict[str, int] = {}
+        self.counts_close: dict[str, int] = {}
         self.lateness: list[float] = []
-        self.recording = False     # record StepRecords (while tracing)
+        #: id() of each request the engine refused (the scheduler's
+        #: ``rejected``); a request queued for free pages has ``error`` set
+        #: too, until it is admitted, and has not failed
+        self.rejected: set[int] = set()
 
     @property
     def window_requests(self) -> list[Tracked]:
         return [t for t in self.tracked if t.in_window]
-
-
-def _step_work(engine) -> tuple[str, int, float, int]:
-    """What the next engine.step() will compute, read from the engine's
-    state before the call: (kind, real tokens, keys attended summed over
-    real tokens, rows whose logits are served)."""
-    decoding = [len(r.prompt) + len(r.generated) - 1
-                for s, r in engine.slot_req.items() if engine.active[s]]
-    keys = float(sum(p + 1 for p in decoding))
-    if engine.prefilling:
-        chunk, real, rows = engine.prefill_chunk, len(decoding), len(decoding)
-        for req in engine.prefilling.values():
-            c = min(chunk, len(req.prompt) - req.prefill_pos)
-            p0 = req.prefill_pos
-            real += c
-            keys += c * p0 + c * (c + 1) / 2
-            rows += int(p0 + c == len(req.prompt))
-        return "chunk", real, keys, rows
-    return "decode", len(decoding), keys, len(decoding)
 
 
 def _annotate(obj, attr: str, name: str):
@@ -212,23 +191,6 @@ def _annotate(obj, attr: str, name: str):
     setattr(obj, attr, wrapped)
 
 
-def record_steps(run: Run, engine) -> None:
-    """Wrap engine.step so that, while the profiler runs, each step's work
-    (read from the engine's state once admissions are done) and wall time
-    are recorded."""
-    step = engine.step
-
-    def wrapped():
-        if not run.recording:
-            return step()
-        work = _step_work(engine)
-        t0 = time.perf_counter()
-        step()
-        run.steps.append(StepRecord(t0, time.perf_counter(), *work))
-
-    engine.step = wrapped
-
-
 def build(cell: Cell, seed: int, *, trace: bool, interpret: bool = False):
     """Weights on the device from the seed (one jitted call), the engine and
     its scheduler, exactly as served."""
@@ -242,14 +204,15 @@ def build(cell: Cell, seed: int, *, trace: bool, interpret: bool = False):
 
     from seeds import model_key
 
-    mc = model_config(cell.config)
+    mc = cell.kind.model_config(cell.config)
     make_params = jax.jit(lambda k: pack_params(init_lm(k, mc), mc))
     params = make_params(model_key(seed))
     e = cell.engine
     engine = Engine(
         params, mc, max_slots=e["max_slots"], max_len=e["max_len"],
         prefill_chunk=e["prefill_chunk"], temperature=0.0,
-        paged_kv=PagedKVConfig(page_size=e["page_size"]),
+        paged_kv=PagedKVConfig(page_size=e["page_size"],
+                               n_pages=e.get("n_pages", 0)),
         mpgemm_interpret=True if interpret else None,
         obs=obs_mod.ObsConfig(trace_capacity=1 << 22) if trace else None,
     )
@@ -279,6 +242,55 @@ def warm_up(cell: Cell, engine, sched) -> None:
     sched.completed.clear()
 
 
+def prime(cell: Cell, seed: int, engine, sched) -> None:
+    """Serve each shared prefix of the mix once (``traffic.prime_prompts``),
+    so that the prefix index holds them before the traffic starts."""
+    from repro.serve.engine import Request
+    from traffic import prime_prompts
+
+    prompts = prime_prompts(cell.mix, seed, cell.config["vocab_size"])
+    if not prompts:
+        return
+    sched.submit([Request(rid=-1000 - i, prompt=p, max_new_tokens=1)
+                  for i, p in enumerate(prompts)])
+    while sched.queue or engine.has_work:
+        sched.tick()
+    engine.reset_stats()
+    sched.completed.clear()
+
+
+def set_up(cell: Cell, seed: int, *, trace: bool, interpret: bool = False,
+           marks: dict | None = None):
+    """The engine and its scheduler, built, warmed up and primed; the clock
+    at the end of each phase goes into ``marks``."""
+    marks = {} if marks is None else marks
+    engine, sched = build(cell, seed, trace=trace, interpret=interpret)
+    marks["built"] = time.perf_counter()
+    warm_up(cell, engine, sched)
+    marks["warmed up"] = time.perf_counter()
+    prime(cell, seed, engine, sched)
+    marks["primed"] = time.perf_counter()
+    return engine, sched
+
+
+def token_counts(engine) -> dict[str, float]:
+    """Tokens served so far: prompt tokens prefilled, prompt tokens read
+    from shared prefix pages, and tokens generated (the engine's counters).
+
+    The engine counts a request's m shared prompt tokens when it admits it;
+    here they are served with its prefill: each of its S - m prefilled
+    tokens brings m / (S - m) of them, so all m are in by its first token.
+    At ~5k shared tokens a request, a few admissions a window, the count
+    at admission moved ``tok_s`` in steps of one admission."""
+    pager, unserved = engine.pager, 0.0
+    for slot, req in engine.prefilling.items():
+        m, size = pager.slot_shared[slot] * pager.page_size, len(req.prompt)
+        unserved += m * (size - req.prefill_pos) / (size - m)
+    return {"prefill_tokens": engine.prefill_tokens,
+            "prefix_hit_tokens": engine.prefix_hit_tokens - unserved,
+            "decode_tokens": engine.decode_tokens}
+
+
 def serve_window(run: Run, engine, sched, counter: CompileCounter) -> dict:
     """The lead-in, the measured window and the drain. Returns diagnostics."""
     import jax
@@ -299,12 +311,8 @@ def serve_window(run: Run, engine, sched, counter: CompileCounter) -> dict:
     nxt = 0
     opened = closed = tracing = False
     compiles_open, compiles_window = 0, -1
-    tok0 = 0
     trace_stop = 0.0
     window_ann = None
-
-    def tokens() -> int:
-        return engine.prefill_tokens + engine.decode_tokens
 
     while True:
         now = time.perf_counter()
@@ -314,7 +322,8 @@ def serve_window(run: Run, engine, sched, counter: CompileCounter) -> dict:
             run.lateness.append(now - t.due)
             nxt += 1
         if not opened and now >= t_base + lead_in:
-            opened, run.t_open, tok0 = True, now, tokens()
+            opened, run.t_open = True, now
+            run.counts_open = token_counts(engine)
             compiles_open = counter.count
             if run.trace:
                 run.span_base = len(engine.obs.tracer.events)
@@ -335,7 +344,9 @@ def serve_window(run: Run, engine, sched, counter: CompileCounter) -> dict:
         if opened and not closed and now >= run.t_open + run.seconds:
             jax.block_until_ready(engine.cache)
             closed, run.t_close = True, time.perf_counter()
-            run.tokens_in_window = tokens() - tok0
+            run.counts_close = token_counts(engine)
+            run.tokens_in_window = sum(run.counts_close.values()) - sum(
+                run.counts_open.values())
             compiles_window = counter.count - compiles_open
             if run.trace:
                 run.spans = [
@@ -345,12 +356,12 @@ def serve_window(run: Run, engine, sched, counter: CompileCounter) -> dict:
             if offline:
                 break
         if closed:
+            refused = {id(r) for r in sched.rejected}
             pending = [t for t in run.window_requests if not t.req.done
-                       and not t.req.error]
+                       and id(t.req) not in refused]
             if not pending or now > run.t_close + drain_cap:
                 break
         if sched.queue or engine.has_work:
-            run.recording = tracing
             sched.tick()
         elif nxt < len(run.tracked):
             wait = run.tracked[nxt].due - time.perf_counter()
@@ -362,6 +373,7 @@ def serve_window(run: Run, engine, sched, counter: CompileCounter) -> dict:
         jax.block_until_ready(engine.cache)
         window_ann.__exit__(None, None, None)
         jax.profiler.stop_trace()
+    run.rejected = {id(r) for r in sched.rejected}
     return {"compiles_in_window": compiles_window, "requests_offered": nxt}
 
 
@@ -399,7 +411,7 @@ def reference_module(cfg: dict):
 
 
 def served_gaps(cfg: dict, seed: int, items: list[tuple[np.ndarray, list[int]]],
-                length: int, lowp=None, control: bool = False) -> list[np.ndarray]:
+                lowp=None, control: bool = False) -> list[np.ndarray]:
     """For each (prompt, served tokens): the gap by which each served token's
     reference logit lies below the reference's best at its position. With
     ``control`` the served tokens are ignored and the tokens that a
@@ -412,10 +424,10 @@ def served_gaps(cfg: dict, seed: int, items: list[tuple[np.ndarray, list[int]]],
         toks = np.concatenate([prompt, np.asarray(gen[:-1], np.int32)])
         pos = np.arange(len(prompt) - 1, len(prompt) - 1 + len(gen))
         seqs.append((toks, pos))
-    ref = mod.Reference(cfg, model_key(seed)).logits(seqs, length)
+    ref = mod.Reference(cfg, model_key(seed)).logits(seqs)
     picks = ([np.asarray(g) for _, g in items] if not control else
              [l.argmax(-1) for l in
-              mod.Reference(cfg, model_key(seed), lowp=lowp).logits(seqs, length)])
+              mod.Reference(cfg, model_key(seed), lowp=lowp).logits(seqs)])
     return [r.max(-1) - np.take_along_axis(r, p[:, None], -1)[:, 0]
             for r, p in zip(ref, picks)]
 
@@ -431,8 +443,7 @@ def check_correct(run: Run) -> dict:
     if picked:
         items = [(np.asarray(t.req.prompt, np.int32), list(t.req.generated))
                  for t in picked]
-        gaps = served_gaps(run.cell.config, run.seed, items,
-                           int(run.cell.engine["max_len"]))
+        gaps = served_gaps(run.cell.config, run.seed, items)
         widest = float(max(g.max() for g in gaps))
         checks["served_tokens"] = {"value": int(sum(len(g) for g in gaps)),
                                    "limit": int(run.cell.check["min_tokens"])}
@@ -448,18 +459,18 @@ def check_correct(run: Run) -> dict:
 def end_to_end(run: Run, setup_s: float) -> tuple[dict, int, int]:
     """The cell's end-to-end metrics, attempted and failed."""
     wr = run.window_requests
-    failed = [t for t in wr if t.req.error or not t.req.done]
+    refused = [t for t in wr if id(t.req) in run.rejected]
     values = {"setup_s": setup_s}
     if run.cell.mix["arrival"] == "backlog":
         finished = [t for t in wr
                     if t.req.done and run.t_open <= t.req.t_done <= run.t_close]
         values["tok_s"] = run.tokens_in_window / (run.t_close - run.t_open)
-        attempted = len(finished) + len([t for t in wr if t.req.error])
-        return values, attempted, len([t for t in wr if t.req.error])
+        return values, len(finished) + len(refused), len(refused)
+    failed = [t for t in wr if not t.req.done]
     cap = max(time.perf_counter(), run.t_close)
     # a failed request misses every limit: it counts with what it waited
-    ttft = [t.req.t_first_token - t.due if t.req.done and not t.req.error
-            else cap - t.due for t in wr]
+    ttft = [t.req.t_first_token - t.due if t.req.done else cap - t.due
+            for t in wr]
     values["ttft_p95_ms"] = 1e3 * percentile(ttft, 0.95)
     return values, len(wr), len(failed)
 
@@ -479,8 +490,6 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     """One run of one cell. Returns the result line's object."""
     import jax
 
-    from costs import peaks_for
-
     log = log or (lambda s: print(s, file=sys.stderr, flush=True))
     devices = jax.devices()
     platform, kind = devices[0].platform, devices[0].device_kind
@@ -488,12 +497,16 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         raise SystemExit(
             f"needs {cell.chips} TPU chip(s); JAX sees {len(devices)} "
             f"{platform} device(s) ({kind})")
-    peaks = peaks if peaks is not None else peaks_for(kind)
+    peaks = peaks if peaks is not None else costs.peaks_for(kind)
     counter = CompileCounter()
-    engine, sched = build(cell, seed, trace=trace, interpret=interpret)
-    warm_up(cell, engine, sched)
-    log(f"[setup] warm-up done at {time.perf_counter() - t_process:.3f}s, "
-        f"{counter.count} compilations and traces")
+    marks = {}
+    engine, sched = set_up(cell, seed, trace=trace, interpret=interpret,
+                           marks=marks)
+    log("[setup] " + ", ".join(f"{k} at {t - t_process:.3f}s"
+                               for k, t in marks.items())
+        + f"; {counter.count} compilations and traces, "
+        f"{counter.compile_s:.3f}s compiling, persistent cache "
+        f"{counter.cache['hits']} hits {counter.cache['misses']} misses")
     if trace:
         for attr, name in (("step", "engine.step"),
                            ("_chunk_verify", "engine.chunk_verify dispatch"),
@@ -504,8 +517,6 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         _annotate(sched, "tick", "scheduler.tick")
     run = Run(cell, seed, seconds, trace)
     run.peaks = peaks
-    if trace:
-        record_steps(run, engine)
     diag = serve_window(run, engine, sched, counter)
     setup_s = run.t_base - t_process
     late = sorted(run.lateness)
@@ -513,6 +524,12 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         f"{diag['requests_offered']}, due in window {len(run.window_requests)}, "
         f"compilations in window {diag['compiles_in_window']}, generator late "
         f"p50 {1e3 * late[len(late) // 2]:.3f} ms max {1e3 * late[-1]:.3f} ms")
+    first = [t.req for t in run.tracked[:cell.engine["max_slots"]]]
+    first_done = (f"{max(r.t_done for r in first) - run.t_base:.3f}s"
+                  if all(r.done for r in first) else "not all")
+    log(f"[window] token counts at open {run.counts_open}, at close "
+        f"{run.counts_close}; the first {len(first)} requests done at "
+        f"{first_done} of the lead-in's {float(cell.mix['lead_in_s'])}s")
     memory_peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
                       for d in devices[:cell.chips])
     device = {"platform": platform, "kind": kind, "count": cell.chips,

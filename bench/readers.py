@@ -42,23 +42,6 @@ def mpgemm_roofline_pct(run) -> float | None:
     return 100.0 * least / sum(c["seconds"] for c in calls)
 
 
-def step_mfu_pct(run) -> float | None:
-    """The traced steps' useful work at the chip's peaks, over their summed
-    wall time, in per cent: the ternary matmuls of real tokens at the int8
-    peak, attention over the keys each real token needs and the head rows
-    that served a token at the bf16 peak. Padding counts as loss."""
-    if not run.steps:
-        return None
-    cfg, pk = run.cell.config, run.peaks
-    mm = costs.mpgemm_ops_per_token(cfg)
-    at_peak = sum(
-        s.real_tokens * mm / pk["int8_ops"]
-        + (costs.attention_ops(cfg, s.attn_keys)
-           + s.logit_rows * costs.head_ops(cfg)) / pk["bf16_flops"]
-        for s in run.steps)
-    return 100.0 * at_peak / sum(s.t1 - s.t0 for s in run.steps)
-
-
 def idle_share_pct(run) -> float | None:
     """Share of the traced window in which no operation ran on the device."""
     td = run.trace_data

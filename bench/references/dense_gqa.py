@@ -21,7 +21,10 @@ and rescaled by both scales.
 The weights are not taken from the program: they are derived here from the
 run's seed by the recipe the benchmark builds the served model with (normal
 draws scaled by 1/sqrt(fan_in) and stored in bfloat16, the embedding scaled
-by 0.02, norm scales 1). Every matmul runs at ``precision="highest"``.
+by 0.02, norm scales 1). Every matmul runs at ``precision="highest"``,
+except the integer-by-ternary product inside a ternary linear: its inputs
+are exact in bfloat16 and its float32 sums are exact (``_exact_product``),
+so one bfloat16 pass gives the float32 result bit for bit.
 
 ``lowp`` rounds every tensor that the served program keeps in bfloat16 to a
 lower type (for the control run that must come out not correct); ``None``
@@ -37,6 +40,9 @@ import numpy as np
 
 EPS_SCALE = 1e-6
 Q_MAX = 127.0
+#: query rows a block of attention computes at once; sequences are padded
+#: to a whole number of blocks
+QUERY_BLOCK = 1024
 
 
 def _linear_weight(key, k_in: int, m_out: int) -> jax.Array:
@@ -77,12 +83,23 @@ def _mm(a, b):
     return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
 
 
+def _exact_product(xq, t):
+    """xq @ t for integers |xq| <= 127 and ternary t, both exact in
+    bfloat16: every product is an integer under 128 and every sum over K
+    inputs is one under 127 * K < 2**24, exact in float32 whatever the
+    order of the additions."""
+    if Q_MAX * xq.shape[-1] >= 2 ** 24:
+        raise ValueError(f"K = {xq.shape[-1]}: float32 sums would round")
+    return jnp.matmul(xq.astype(jnp.bfloat16), t.astype(jnp.bfloat16),
+                      preferred_element_type=jnp.float32)
+
+
 def _ternary_linear(x, tw, lowp):
     t, w_scale = tw
     amax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
     a_scale = jnp.maximum(amax, EPS_SCALE) / Q_MAX
     xq = jnp.clip(jnp.round(x / a_scale), -Q_MAX, Q_MAX)
-    return _round(_mm(xq, t) * a_scale * w_scale[None, :], lowp)
+    return _round(_exact_product(xq, t) * a_scale * w_scale[None, :], lowp)
 
 
 def _rmsnorm(x, eps, lowp):
@@ -99,7 +116,27 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
 
 
-def _layer(w, x, *, cfg, lowp):
+def _attention(q, k, v, lowp, block):
+    """Causal GQA attention of q (S, H, D) over k, v (S, KV, D), in blocks of
+    ``block`` query rows: each block's scores span only the keys at or
+    before its last row, so a long sequence never holds (S, S) scores."""
+    s, kv, hd = k.shape
+    h = q.shape[1]
+    qg = q.reshape(s, kv, h // kv, hd)
+    out = []
+    for i0 in range(0, s, block):
+        i1 = min(i0 + block, s)
+        scores = jnp.einsum("qkgd,skd->kgqs", qg[i0:i1], k[:i1],
+                            precision=jax.lax.Precision.HIGHEST) * hd ** -0.5
+        causal = (jnp.arange(i0, i1)[:, None] >= jnp.arange(i1)[None, :])
+        p = jax.nn.softmax(jnp.where(causal[None, None], scores, -jnp.inf),
+                           axis=-1)
+        out.append(jnp.einsum("kgqs,skd->qkgd", _round(p, lowp), v[:i1],
+                              precision=jax.lax.Precision.HIGHEST))
+    return jnp.concatenate(out).reshape(s, h * hd)
+
+
+def _layer(w, x, *, cfg, lowp, block=QUERY_BLOCK):
     s = x.shape[0]
     h, kv, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
                  cfg["head_dim"])
@@ -108,14 +145,7 @@ def _layer(w, x, *, cfg, lowp):
     q = _round(_rope(_ternary_linear(hn, w["q"], lowp).reshape(s, h, hd), theta), lowp)
     k = _round(_rope(_ternary_linear(hn, w["k"], lowp).reshape(s, kv, hd), theta), lowp)
     v = _ternary_linear(hn, w["v"], lowp).reshape(s, kv, hd)
-    qg = q.reshape(s, kv, h // kv, hd)
-    scores = jnp.einsum("qkgd,skd->kgqs", qg, k,
-                        precision=jax.lax.Precision.HIGHEST) * hd ** -0.5
-    causal = jnp.tril(jnp.ones((s, s), bool))
-    p = jax.nn.softmax(jnp.where(causal[None, None], scores, -jnp.inf), axis=-1)
-    att = jnp.einsum("kgqs,skd->qkgd", _round(p, lowp), v,
-                     precision=jax.lax.Precision.HIGHEST)
-    att = _round(att.reshape(s, h * hd), lowp)
+    att = _round(_attention(q, k, v, lowp, block), lowp)
     x = _round(x + _ternary_linear(att, w["o"], lowp), lowp)
     hn = _rmsnorm(x, eps, lowp)
     g = _round(jax.nn.silu(_ternary_linear(hn, w["w1"], lowp))
@@ -147,13 +177,16 @@ class Reference:
         return _linear_weight(jax.random.fold_in(self.root, 1),
                               c["hidden_size"], c["vocab_size"])
 
-    def logits(self, seqs: list[tuple[np.ndarray, np.ndarray]],
-               length: int) -> list[np.ndarray]:
+    def logits(self, seqs: list[tuple[np.ndarray, np.ndarray]]
+               ) -> list[np.ndarray]:
         """For each (tokens (S,), positions (n,)) the logits (n, vocab) that
         follow tokens[:p+1] at each position p. Sequences are padded at the
-        end to ``length`` (causal: padding never reaches earlier rows), so
-        one program serves them all."""
+        end to the longest of them, rounded up to a whole query block
+        (causal: padding never reaches earlier rows), so one program serves
+        them all."""
         c = self.cfg
+        longest = max(len(tokens) for tokens, _ in seqs)
+        length = -(-longest // QUERY_BLOCK) * QUERY_BLOCK
         embed = jax.jit(lambda t, ids: _round(t[ids], self.lowp))
         table = self._embedding()
         xs = []

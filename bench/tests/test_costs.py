@@ -39,9 +39,30 @@ def test_least_time_is_the_binding_roof():
 
 def test_ops_per_token_of_the_configurations():
     # internlm2-1.8b: 24 layers of 62.9M ternary weights, 3.0 GOP a token
-    assert costs.mpgemm_ops_per_token(_cfg("internlm2-1.8b")) == pytest.approx(
-        2 * 24 * (2 * 2048 * 2048 + 2 * 2048 * 1024 + 3 * 2048 * 8192))
-    assert 3.0e9 < costs.mpgemm_ops_per_token(_cfg("internlm2-1.8b")) < 3.05e9
     lm = _cfg("internlm2-1.8b")
-    assert costs.head_ops(lm) == 2 * 2048 * 92544
-    assert costs.attention_ops(lm, 100) == 4 * 16 * 128 * 100 * 24
+    kind = costs.kind(lm)
+    assert kind.__name__ == "kind_dense_gqa"
+    assert kind.mpgemm_ops_per_token(lm) == pytest.approx(
+        2 * 24 * (2 * 2048 * 2048 + 2 * 2048 * 1024 + 3 * 2048 * 8192))
+    assert 3.0e9 < kind.mpgemm_ops_per_token(lm) < 3.05e9
+    assert kind.head_ops(lm) == 2 * 2048 * 92544
+    assert kind.attention_ops(lm, 100) == 4 * 16 * 128 * 100 * 24
+    assert kind.linear_shapes(lm) == [(2048, 2048), (1024, 2048), (1024, 2048),
+                                      (2048, 2048), (8192, 2048), (8192, 2048),
+                                      (2048, 8192)]
+
+
+def test_dense_gqa_kind_builds_the_programs_config():
+    from repro.configs import get_config
+
+    mc = costs.kind(_cfg("internlm2-1.8b")).model_config(_cfg("internlm2-1.8b"))
+    ref = get_config("internlm2-1.8b")
+    for f in ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim",
+              "d_ff", "vocab", "tie_embeddings"):
+        assert getattr(mc, f) == getattr(ref, f), f
+    assert mc.dtype == "bfloat16"
+
+
+def test_unknown_kind_names_the_missing_file():
+    with pytest.raises(FileNotFoundError, match=r"kinds/mamba_hybrid\.py"):
+        costs.kind({**_cfg("internlm2-1.8b"), "kind": "mamba_hybrid"})

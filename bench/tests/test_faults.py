@@ -34,7 +34,8 @@ def _wrap_build(monkeypatch, wrap):
 
 
 @pytest.mark.parametrize("mix, metric", [(tiny.OFFLINE, "tok_s"),
-                                          (tiny.CHAT, "ttft_p95_ms")])
+                                          (tiny.CHAT, "ttft_p95_ms"),
+                                          (tiny.AGENT, "tok_s")])
 def test_sound_path_is_correct(mix, metric):
     out = _run(mix)
     assert out["correct"] is True

@@ -4,10 +4,12 @@ from __future__ import annotations
 
 import json
 
+import costs
 from harness import BENCH, Cell
 
 CONFIG = {
-    "name": "tiny", "reference": "dense_gqa", "num_hidden_layers": 2,
+    "name": "tiny", "kind": "dense_gqa", "reference": "dense_gqa",
+    "num_hidden_layers": 2,
     "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 2,
     "head_dim": 16, "intermediate_size": 128, "vocab_size": 512,
     "rope_theta": 10000.0, "rms_norm_eps": 1e-5, "tie_word_embeddings": False,
@@ -25,6 +27,16 @@ CHAT = {
     "prompt": {"dist": "lognormal", "median": 24, "sigma": 0.8, "min": 4, "max": 64},
     "output": {"dist": "lognormal", "median": 8, "sigma": 0.5, "min": 2, "max": 16},
     "lead_in_s": 0.3, "tail_s": 5.0, "drain_cap_s": 20.0, "shape_seed": 5,
+}
+AGENT = {
+    "arrival": "backlog", "backlog": 3000,
+    "shared": {
+        "prefixes": 2,
+        "prefix": {"dist": "lognormal", "median": 40, "sigma": 0.2, "min": 32, "max": 48},
+    },
+    "prompt": {"dist": "lognormal", "median": 12, "sigma": 0.5, "min": 8, "max": 32},
+    "output": {"dist": "lognormal", "median": 4, "sigma": 0.6, "min": 2, "max": 8},
+    "lead_in_s": 0.3, "shape_seed": 5,
 }
 CHECK = {"tokens": 40, "min_tokens": 10, "min_requests": 4, "max_requests": 8,
          "gap_limit": 0.1}
@@ -44,9 +56,10 @@ def cell(mix: dict | None = None, **config) -> Cell:
     else:
         e2e.append(TTFT)
     reported = {m["name"] for m in e2e}
+    config = {**CONFIG, **config}
     return Cell(
         name=f"tiny.{'prefill_offline' if mix['arrival'] == 'backlog' else 'chat'}",
-        config={**CONFIG, **config}, mix=mix, engine=dict(ENGINE),
+        config=config, kind=costs.kind(config), mix=mix, engine=dict(ENGINE),
         check=dict(CHECK), end_to_end=e2e,
         per_layer=[m for m in bench["per_layer"] if m["moves"] in reported],
     )

@@ -10,14 +10,25 @@ kinds exist:
   the load on while the window's own requests finish).
 * ``backlog``: ``backlog`` requests all due at time 0 (an offline job).
 
-The sizes and the inter-arrival gaps of every segment are drawn once from
-the mix's fixed ``shape_seed``; for an open loop ``--seed`` permutes them
-inside their segment, so every seed offers the window the same work in
-another order. A backlog keeps the drawn order: a window serves only the
-head of the backlog, so its order is its work, and any reordering handed
-each seed other prompt lengths (tok_s 530-736 over six seeds on a TPU v5e,
-where two runs of one seed agreed within a few per cent). ``--seed``
-always draws the prompts' token ids.
+A mix may share prefixes (an agent job): a block
+
+    "shared": {"prefixes": P, "prefix": <length spec>}
+
+gives P shared prompts (a system prompt with its tool schemas), and
+request i's prompt is prefix i mod P followed by its own ``prompt`` tokens
+(the task: its instructions, tool results and files). The harness serves
+each prefix once at the end of set-up (``prime_prompts``), as a deployed
+agent service has its system prompts cached.
+
+The sizes and the inter-arrival gaps of every segment, and the shared
+prefixes' sizes, are drawn once from the mix's fixed ``shape_seed``; for
+an open loop ``--seed`` permutes them inside their segment, so every seed
+offers the window the same work in another order. A backlog keeps the
+drawn order: a window serves only the head of the backlog, so its order is
+its work, and any reordering handed each seed other prompt lengths (tok_s
+530-736 over six seeds on a TPU v5e, where two runs of one seed agreed
+within a few per cent). ``--seed`` always draws the prompts' token ids,
+each prefix's once, so that requests really share them.
 """
 from __future__ import annotations
 
@@ -53,10 +64,32 @@ def _segment_gaps(n: int, length_s: float, rng: np.random.Generator) -> np.ndarr
     return gaps * (length_s / gaps.sum())
 
 
+def _rngs(mix: dict, seed: int) -> tuple[np.random.Generator, np.random.Generator]:
+    """(sizes from the mix's shape_seed, order and ids from the run's seed)."""
+    return (np.random.default_rng(int(mix["shape_seed"])),
+            np.random.default_rng([int(seed) & 0xFFFFFFFF, int(seed) >> 32, 1]))
+
+
+def _prefixes(mix: dict, shape, order, vocab: int) -> list[np.ndarray]:
+    """The shared prefixes: the first draws of both generators, so
+    ``prime_prompts`` gives the ids ``generate`` uses."""
+    spec = mix.get("shared")
+    if not spec:
+        return []
+    lengths = _lengths(spec["prefix"], int(spec["prefixes"]), shape)
+    return [order.integers(0, vocab, int(n), dtype=np.int32) for n in lengths]
+
+
+def prime_prompts(mix: dict, seed: int, vocab: int) -> list[np.ndarray]:
+    """The prompts set-up serves once before the traffic: the shared
+    prefixes, if any."""
+    return _prefixes(mix, *_rngs(mix, seed), vocab)
+
+
 def generate(mix: dict, seed: int, seconds: float, vocab: int) -> list[Arrival]:
     """The arrivals of one run, in due order."""
-    shape = np.random.default_rng(int(mix["shape_seed"]))
-    order = np.random.default_rng([int(seed) & 0xFFFFFFFF, int(seed) >> 32, 1])
+    shape, order = _rngs(mix, seed)
+    prefixes = _prefixes(mix, shape, order, vocab)
     if mix["arrival"] == "backlog":
         segments = [(int(mix["backlog"]), 0.0, True)]
     elif mix["arrival"] == "poisson":
@@ -81,9 +114,13 @@ def generate(mix: dict, seed: int, seconds: float, vocab: int) -> list[Arrival]:
             gaps = gaps[order.permutation(n)]
         due = start + np.concatenate([[0.0], np.cumsum(gaps)[:-1]]) if n else gaps
         for i in range(n):
+            prompt = order.integers(0, vocab, int(prompts[i]), dtype=np.int32)
+            if prefixes:
+                prefix = prefixes[len(out) % len(prefixes)]
+                prompt = np.concatenate([prefix, prompt])
             out.append(Arrival(
                 due_s=float(due[i]),
-                prompt=order.integers(0, vocab, int(prompts[i]), dtype=np.int32),
+                prompt=prompt,
                 max_new_tokens=int(outputs[i]),
                 in_window=in_window,
             ))
